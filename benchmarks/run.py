@@ -21,6 +21,8 @@ import time
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.configure()
     from benchmarks import (dist_batch_bench, fig1_auc_scaling,
                             fig2_time_scaling, fig3_depth_metrics,
                             forest_batch_bench, hist_mode_bench,

@@ -1,14 +1,13 @@
 """Distributed DRF: train the SAME forest with the 2-D sharded supersplit
 engine (feature columns over "model" splitters, presorted rows over "data")
 and verify it is bit-identical to the single-machine build — the paper's
-exactness guarantee, demonstrated on an 8-device host mesh.
+exactness guarantee, on a mesh over the devices this process sees.
 
-  python examples/distributed_forest.py      (sets its own XLA_FLAGS)
+  python examples/distributed_forest.py      # the chips present (TPU)
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      python examples/distributed_forest.py  # a 2x4 mesh of host devices
 """
 import os
-
-if __name__ == "__main__" and "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -22,7 +21,7 @@ from repro.launch.mesh import make_host_mesh
 
 
 def main() -> None:
-    mesh = make_host_mesh(data=2, model=4)
+    mesh = make_host_mesh()
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"({mesh.devices.size} devices)")
 

@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import splits
 from repro.core.level.sharded import (ShardedCategorical,  # noqa: F401
                                       ShardedExactNumeric,
-                                      ShardedHistNumeric, _shmap, shard_map)
+                                      ShardedHistNumeric, _shmap)
 
 
 def make_column_sharded_supersplit(mesh, feature_axis: str = "model"):

@@ -187,7 +187,16 @@ def _forest_predict_impl(feature, threshold, is_cat, cat_mask, children,
 
     preds = jax.vmap(one_tree)(feature, threshold, is_cat, cat_mask,
                                children, value)               # (T, B, C)
-    return preds.mean(axis=0) if reduce_mean else preds
+    if not reduce_mean:
+        return preds
+    # trees summed one by one, then one division: elementwise steps in a
+    # fixed order, so a row's answer does not depend on the batch it
+    # comes in.  A TPU associates a `mean` reduction differently per batch
+    # shape (last-bit differences between ForestServer's batches and a
+    # whole-set predict_proba on a v5e).
+    total = jax.lax.fori_loop(1, preds.shape[0],
+                              lambda t, acc: acc + preds[t], preds[0])
+    return total / preds.shape[0]
 
 
 _forest_predict = jax.jit(

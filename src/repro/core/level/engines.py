@@ -49,7 +49,7 @@ class LevelInputs(NamedTuple):
     leaf id at the previous level, `sib_of[l]` its sibling's current id,
     `slot_of[l]` its packed build slot (0 = table derived by subtraction).
     """
-    num: jnp.ndarray           # (n, m_num) raw numeric columns
+    num: jnp.ndarray           # (m_num, n) raw numeric columns, feature-major
     cat: jnp.ndarray           # (n, m_cat) raw categorical columns
     labels: jnp.ndarray        # (n,) class ids / regression targets
     sorted_vals: jnp.ndarray   # (m_num, n) presorted values (or (0, 0))
@@ -62,7 +62,7 @@ class LevelInputs(NamedTuple):
     stats: jnp.ndarray         # (n, S) row stats
     totals: jnp.ndarray        # (L+1, S) per-leaf stat totals
     row_counts: jnp.ndarray    # (L+1,) rows per leaf (leaf-ordered layout)
-    prev_tables: jnp.ndarray = None   # (m_num, Wprev, B, S) previous level
+    prev_tables: jnp.ndarray = None   # (m_num, Wprev, S, B) previous level
     parent_of: jnp.ndarray = None     # (L+1,) parent leaf id at prev level
     sib_of: jnp.ndarray = None        # (L+1,) sibling's current leaf id
     slot_of: jnp.ndarray = None       # (L+1,) packed build slot, 0 = derive
@@ -125,7 +125,7 @@ class SplitEngine:
     # chunk recurrence: `stream_init` allocates the per-level accumulator,
     # `stream_accumulate` adds one fixed-shape row chunk (called inside
     # the jitted chunk step, once per chunk), and `stream_finalize` merges
-    # the accumulator into the (T, m_num, L+1, B, S) tables the scorer
+    # the accumulator into the (T, m_num, L+1, S, B) tables the scorer
     # reads (called once per level).  Classification tables are
     # integer-valued f32, so chunked accumulation is bit-equal to the
     # single-pass scatter regardless of chunk boundaries.
@@ -143,7 +143,7 @@ class SplitEngine:
         raise NotImplementedError
 
     def stream_finalize(self, acc):
-        """Accumulator -> merged (T, m_num, Lp+1, B, S) tables."""
+        """Accumulator -> merged (T, m_num, Lp+1, S, B) tables."""
         raise NotImplementedError
 
 
@@ -217,9 +217,12 @@ class ExactNumeric(SplitEngine):
             tot = inp.totals if st.task == "classification" else None
             lf_pos = inp.leaf_of[inp.ord_idx[0]]    # same for every column
             inbag = (inp.w > 0)[inp.ord_idx] & (lf_pos > 0)[None]
-            ord_vals = jnp.take_along_axis(inp.num.T, inp.ord_idx, axis=1)
+            ord_vals = jnp.take_along_axis(inp.num, inp.ord_idx, axis=1)
+            ord_stats = splits.stat_planes(
+                inp.labels[inp.ord_idx], inp.w[inp.ord_idx],
+                inp.stats.shape[-1], st.task)                # (S, m, n)
             return splits.best_numeric_split_leaf_ordered(
-                ord_vals, lf_pos, inbag, inp.stats[inp.ord_idx], cand, Lp,
+                ord_vals, lf_pos, inbag, ord_stats, cand, Lp,
                 st.impurity, st.task, st.min_records, totals=tot,
                 row_counts=inp.row_counts)
         return _numeric_supersplits(
@@ -263,12 +266,12 @@ def _hist_build_rows(inp, subtract, compact):
 def _expand_subtracted(packed, prev_tables, parent_of, sib_of, slot_of):
     """Full-width tables from packed build tables + the parent recurrence.
 
-    packed: (m, Wb, B, S) merged build-slot tables; returns (m, L+1, B, S)
+    packed: (m, Wb, S, B) merged build-slot tables; returns (m, L+1, S, B)
     where build leaves gather their packed slot and every derive leaf is
     `parent − sibling` — exact for classification (integer-valued counts),
     which is why the plan only enables subtraction there.
     """
-    from_build = packed[:, slot_of]                       # (m, L+1, B, S)
+    from_build = packed[:, slot_of]                       # (m, L+1, S, B)
     sib = packed[:, slot_of[sib_of]]
     derived = prev_tables[:, parent_of] - sib
     return jnp.where((slot_of > 0)[None, :, None, None], from_build, derived)
@@ -297,12 +300,17 @@ class HistNumeric(SplitEngine):
 
     def stream_init(self, T, st, Lp):
         S = st.num_classes if st.task == "classification" else 3
-        return jnp.zeros((T, st.m_num, Lp + 1, st.num_bins, S), jnp.float32)
+        return jnp.zeros((T, st.m_num, Lp + 1, S, st.num_bins), jnp.float32)
 
     def stream_accumulate(self, acc, bins, leaf, w, stats, labels, st, Lp):
-        return acc + jax.vmap(
-            lambda lf, ww, stt: self._tables(None, st, Lp + 1, bins, lf, ww,
-                                             stt, labels))(leaf, w, stats)
+        if self.backend == "kernel":
+            return acc + jax.vmap(
+                lambda lf, ww, stt: self._tables(None, st, Lp + 1, bins, lf,
+                                                 ww, stt, labels))(
+                leaf, w, stats)
+        # the tree axis folds into the one flat scatter (no vmap)
+        return acc + splits.feature_count_tables(bins, leaf, w, stats, Lp,
+                                                 st.num_bins)
 
     def stream_finalize(self, acc):
         return acc
@@ -339,7 +347,7 @@ class HistNumeric(SplitEngine):
 class CategoricalTable(SplitEngine):
     """Exact categorical search from (leaf × category × stat) count tables
     + Breiman ordering; backend="kernel" builds the tables with the Pallas
-    cat_hist kernel."""
+    table kernel (`kernels/feat_hist.py`)."""
     backend: str = "segment"
 
     kind = "categorical"

@@ -292,6 +292,7 @@ def _eval_conditions_core(num, cat, leaf_of, feat_of_leaf, thr_of_leaf,
     the float columns: `thr_of_leaf` then holds the winning BIN INDEX and
     `bin <= cut  <=>  x <= edges[cut]` (presort.quantize_edges), so the
     partition is identical while the program never reads float32 columns.
+    `num` is feature-major, (m_num, n): see `tree._level_num`.
     """
     f = feat_of_leaf[leaf_of]                                   # (n,)
     jn = jnp.clip(f, 0, max(m_num - 1, 0))
@@ -300,7 +301,7 @@ def _eval_conditions_core(num, cat, leaf_of, feat_of_leaf, thr_of_leaf,
         xbin = bin_of[jn, jnp.arange(leaf_of.shape[0])].astype(jnp.int32)
         num_bit = xbin <= thr_of_leaf[leaf_of].astype(jnp.int32)
     else:
-        xnum = (jnp.take_along_axis(num, jn[:, None], axis=1)[:, 0]
+        xnum = (num[jn, jnp.arange(leaf_of.shape[0])]
                 if num.size else jnp.zeros_like(leaf_of, jnp.float32))
         num_bit = xnum <= thr_of_leaf[leaf_of]
     xcat = jnp.take_along_axis(cat, jc[:, None], axis=1)[:, 0] if cat.size else jnp.zeros_like(leaf_of)
@@ -512,7 +513,7 @@ def _fused_level_step_batched(num, cat, labels, sorted_vals, sorted_idx,
     batching under the shared `Lp` is bit-identical per tree to the
     per-tree `_fused_level_step` under that tree's own padding — the
     property tests/test_forest_batch.py asserts against the reference
-    builder.  The Pallas paths (`split_scan`, `cat_hist`) batch through
+    builder.  The Pallas paths (`split_scan`, `feat_hist`) batch through
     `pallas_call`'s vmap rule, which folds the tree axis into the kernel
     grid — still one device program.
 
@@ -681,7 +682,7 @@ def _fused_level_step_batched(num, cat, labels, sorted_vals, sorted_idx,
 #                          totals the host reads for node values.
 #   _stream_score_step     per level: candidate draw + histogram scoring +
 #                          the EXACT `_level_step_core` winner/child-id
-#                          formulas, on (T, m, L+1, B, S) tables alone —
+#                          formulas, on (T, m, L+1, S, B) tables alone —
 #                          engine-independent, no row state.
 #
 # Classification tables are integer-valued f32, so the chunked
@@ -740,12 +741,12 @@ def _stream_chunk_step(bins_c, labels_c, w_c, leaf_prev_c, feat_of_leaf,
 def _stream_finalize_step(tables, *, plan):
     """Merge the chunk accumulator and reduce per-leaf totals.
 
-    Returns (merged (T, m, L+1, B, S) tables, totals (T, L+1, S)).  The
+    Returns (merged (T, m, L+1, S, B) tables, totals (T, L+1, S)).  The
     totals come from feature 0's table summed over bins — for integer
     classification stats this equals the direct per-row segment_sum
     bit-for-bit (every in-bag row lands in exactly one bin)."""
     merged = plan.numeric.stream_finalize(tables)
-    return merged, merged[:, 0].sum(axis=2)
+    return merged, merged[:, 0].sum(axis=3)
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "Lp"))

@@ -39,20 +39,9 @@ from jax.sharding import PartitionSpec as P
 from repro.core import splits
 from repro.core.level.engines import SplitEngine, _expand_subtracted
 
-try:  # jax>=0.6 stable name, fall back to experimental
-    from jax import shard_map as _shard_map_mod
-    shard_map = _shard_map_mod.shard_map if hasattr(_shard_map_mod, "shard_map") else _shard_map_mod
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-
 def _shmap(f, mesh, in_specs, out_specs):
-    try:    # jax>=0.6 spells the replication check "check_vma"
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # jax 0.4.x spells it "check_rep"
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,7 +200,7 @@ class ShardedHistNumeric(_MeshEngine):
         from jax.sharding import NamedSharding
         S = st.num_classes if st.task == "classification" else 3
         R = self.row_shards()
-        zeros = jnp.zeros((R, T, st.m_num, Lp + 1, st.num_bins, S),
+        zeros = jnp.zeros((R, T, st.m_num, Lp + 1, S, st.num_bins),
                           jnp.float32)
         if self.row_axis is None:
             return zeros
@@ -221,15 +210,13 @@ class ShardedHistNumeric(_MeshEngine):
     def stream_accumulate(self, acc, bins, leaf, w, stats, labels, st, Lp):
         B = st.num_bins
         if self.row_axis is None:
-            return acc + jax.vmap(
-                lambda lf, ww, stt: splits.feature_count_tables(
-                    bins, lf, ww, stt, Lp, B))(leaf, w, stats)[None]
+            return acc + splits.feature_count_tables(
+                bins, leaf, w, stats, Lp, B)[None]
 
         def local(a, bo, lf, ww, stt):
-            # a (1, T, m_loc, L+1, B, S); bo (m_loc, c_loc); lf/ww (T, c_loc)
-            return a + jax.vmap(
-                lambda l, x, s: splits.feature_count_tables(
-                    bo, l, x, s, Lp, B))(lf, ww, stt)[None]
+            # a (1, T, m_loc, L+1, S, B); bo (m_loc, c_loc); lf/ww (T, c_loc)
+            return a + splits.feature_count_tables(bo, lf, ww, stt, Lp,
+                                                   B)[None]
 
         F, R = self.feature_axis, self.row_axis
         return _shmap(local, self.mesh,
@@ -286,22 +273,18 @@ class ShardedHistNumeric(_MeshEngine):
 
         def local(bo, cl, lf, ww, stt, *sub):
             # bo (m_loc, n_loc); cl (T, m_loc, L+1); lf/ww (T, n_loc);
-            # stt (T, n_loc, S); sub = (prev (T, m_loc, Wprev, B, S),
+            # stt (T, n_loc, S); sub = (prev (T, m_loc, Wprev, S, B),
             # parent/sib/slot (T, L+1)) when subtracting
-            def build(lf_t, ww_t, st_t, slot_t):
-                # NO row compaction here: the build-rows <= n/2 bound is
-                # global, not per row shard — derive rows mask to slot 0
-                ids = slot_t[lf_t] if subtract else lf_t
-                return splits.feature_count_tables(bo, ids, ww_t, st_t,
-                                                   Wb - 1, B)
+            # NO row compaction here: the build-rows <= n/2 bound is
+            # global, not per row shard — derive rows mask to slot 0
             if subtract:
                 prev, par, sib, slot = sub
-                packed = jax.vmap(build)(lf, ww, stt, slot)
+                ids = jax.vmap(lambda sl, l: sl[l])(slot, lf)
             else:
-                packed = jax.vmap(lambda a, b, c: build(a, b, c, None))(
-                    lf, ww, stt)
+                ids = lf
+            packed = splits.feature_count_tables(bo, ids, ww, stt, Wb - 1, B)
             if R is not None:
-                # THE merge: one psum of the (T, m_loc, Wb, B, S) tables —
+                # THE merge: one psum of the (T, m_loc, Wb, S, B) tables —
                 # under subtraction only build slots cross the network
                 packed = jax.lax.psum(packed, R)
             if subtract:

@@ -190,16 +190,20 @@ def streaming_quantile_edges(chunks, n: int, m_num: int,
             P = max(len(u) for u in uniq)
             counts = np.zeros((m_num, P, size), np.int64)
             mask = size - 1
+            # prefix -> its row in counts[j] (-1: no quantile needs it), a
+            # table lookup per key instead of a binary search in uniq[j]
+            slot = np.full(1 << done, -1, np.int64)
             for block in chunks():
                 keys = _float_keys(block)
                 hi = keys >> np.uint32(shift + width)
                 sub = (keys >> np.uint32(shift)).astype(np.int64) & mask
                 for j in range(m_num):
                     u = uniq[j]
-                    idx = np.searchsorted(u, hi[:, j])
-                    idx_c = np.minimum(idx, len(u) - 1)
-                    match = u[idx_c] == hi[:, j]
-                    flat = idx_c[match] * size + sub[:, j][match]
+                    slot[u] = np.arange(len(u))
+                    idx = slot[hi[:, j]]
+                    slot[u] = -1
+                    match = idx >= 0
+                    flat = idx[match] * size + sub[:, j][match]
                     counts[j] += np.bincount(
                         flat, minlength=P * size).reshape(P, size)
             for j in range(m_num):

@@ -72,7 +72,7 @@ def compact_rows(*, keep, drop, leaf_of, ord_idx, sorted_vals, sorted_idx,
                               sorted_idx.reshape(-1)[flat]).reshape(
             m_num, n_new)
         sorted_vals = sorted_vals.reshape(-1)[flat].reshape(m_num, n_new)
-    num = num[keep_idx]
+    num = num[:, keep_idx]                  # feature-major (m_num, n)
     cat = cat[keep_idx]
     labels = labels[keep_idx]
     if batched:

@@ -29,7 +29,7 @@ from repro.core.tree import (LevelStats, Tree, _assemble_tree, _NodeAccum,
 def _eval_conditions_core(num, cat, leaf_of, feat_of_leaf, thr_of_leaf,
                           iscat_of_leaf, mask_of_leaf, m_num):
     from repro.core.level.plan import _eval_conditions_core as impl
-    return impl(num, cat, leaf_of, feat_of_leaf, thr_of_leaf, iscat_of_leaf,
+    return impl(num.T, cat, leaf_of, feat_of_leaf, thr_of_leaf, iscat_of_leaf,
                 mask_of_leaf, m_num)
 
 

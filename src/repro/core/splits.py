@@ -40,26 +40,6 @@ import jax.numpy as jnp
 
 NEG = jnp.float32(-jnp.inf)
 
-# jax<0.5 compat: `optimization_barrier` ships without a vmap batching rule.
-# The barrier is an identity per operand (it only pins values against XLA
-# re-fusion), so batching is a pass-through.  Registered here so the
-# leaf-ordered supersplit below — which pins gain/tau before its
-# associative scan — also lowers under the tree-axis vmap of
-# `tree.build_forest` (DESIGN.md §3).
-try:  # pragma: no cover - newer jax moves these private paths (and ships
-    # the rule built in, making the shim unnecessary); anything else that
-    # goes wrong here should surface, not turn into an opaque vmap error
-    from jax._src.interpreters import batching as _batching
-    from jax._src.lax.lax import optimization_barrier_p as _opt_barrier_p
-
-    if _opt_barrier_p not in _batching.primitive_batchers:
-        def _opt_barrier_batcher(args, dims, **params):
-            return _opt_barrier_p.bind(*args, **params), dims
-        _batching.primitive_batchers[_opt_barrier_p] = _opt_barrier_batcher
-except (ImportError, AttributeError):
-    pass
-
-
 # ---------------------------------------------------------------------------
 # Stats & impurities
 # ---------------------------------------------------------------------------
@@ -73,33 +53,52 @@ def row_stats(labels: jnp.ndarray, weights: jnp.ndarray, num_classes: int,
     return jnp.stack([weights, weights * y, weights * y * y], axis=-1)
 
 
-def count_fn(task: str) -> Callable[[jnp.ndarray], jnp.ndarray]:
+def stat_planes(labels: jnp.ndarray, weights: jnp.ndarray, num_classes: int,
+                task: str) -> jnp.ndarray:
+    """`row_stats` with the stat axis FIRST, (S, *labels.shape), built
+    elementwise so that no (..., S) array is ever materialized."""
     if task == "classification":
-        return lambda h: h.sum(-1)
-    return lambda h: h[..., 0]
+        return jnp.stack([jnp.where(labels == s, weights, 0.0)
+                          for s in range(num_classes)])
+    y = labels.astype(jnp.float32)
+    return jnp.stack([weights, weights * y, weights * y * y])
 
 
-def weighted_impurity(h: jnp.ndarray, impurity: str) -> jnp.ndarray:
-    """N * impurity for a stats accumulator h (..., S). Safe at N=0."""
+# Row- and table-sized arrays keep the stat axis S (2-3 wide) OFF the minor
+# axis: a TPU lays the minor axis out in 128-lane tiles, so an (n, S) or
+# (..., B, S) array occupies 128/S times its size in HBM.  The helpers
+# below therefore take the stat axis as an argument.
+
+def count_fn(task: str, axis: int = -1) -> Callable[[jnp.ndarray], jnp.ndarray]:
+    if task == "classification":
+        return lambda h: h.sum(axis)
+    return lambda h: jnp.take(h, 0, axis=axis)
+
+
+def weighted_impurity(h: jnp.ndarray, impurity: str,
+                      axis: int = -1) -> jnp.ndarray:
+    """N * impurity for a stats accumulator h with its S stats on `axis`.
+    Safe at N=0."""
     if impurity == "gini":
-        n = h.sum(-1)
-        return n - jnp.where(n > 0, (h * h).sum(-1) / jnp.maximum(n, 1e-12), 0.0)
+        n = h.sum(axis)
+        return n - jnp.where(n > 0, (h * h).sum(axis) / jnp.maximum(n, 1e-12), 0.0)
     if impurity == "entropy":
-        n = h.sum(-1, keepdims=True)
+        n = h.sum(axis, keepdims=True)
         p = h / jnp.maximum(n, 1e-12)
         plogp = jnp.where(h > 0, p * jnp.log(jnp.maximum(p, 1e-12)), 0.0)
-        return -(n[..., 0] * plogp.sum(-1))
+        return -(jnp.squeeze(n, axis) * plogp.sum(axis))
     if impurity == "variance":
-        w, wy, wy2 = h[..., 0], h[..., 1], h[..., 2]
+        w, wy, wy2 = (jnp.take(h, i, axis=axis) for i in range(3))
         return jnp.maximum(wy2 - jnp.where(w > 0, wy * wy / jnp.maximum(w, 1e-12), 0.0), 0.0)
     raise ValueError(f"unknown impurity {impurity!r}")
 
 
-def split_gain(left: jnp.ndarray, right: jnp.ndarray, impurity: str) -> jnp.ndarray:
+def split_gain(left: jnp.ndarray, right: jnp.ndarray, impurity: str,
+               axis: int = -1) -> jnp.ndarray:
     parent = left + right
-    return (weighted_impurity(parent, impurity)
-            - weighted_impurity(left, impurity)
-            - weighted_impurity(right, impurity))
+    return (weighted_impurity(parent, impurity, axis)
+            - weighted_impurity(left, impurity, axis)
+            - weighted_impurity(right, impurity, axis))
 
 
 # ---------------------------------------------------------------------------
@@ -282,7 +281,7 @@ def best_numeric_split_leaf_ordered(
     vals: jnp.ndarray,           # (m, n) float32, (leaf, value)-sorted rows
     lf_pos: jnp.ndarray,         # (n,) int32 leaf id PER POSITION (shared)
     inbag: jnp.ndarray,          # (m, n) bool: w > 0 & leaf open, per column
-    stats: jnp.ndarray,          # (m, n, S) row stats in leaf order
+    stats: jnp.ndarray,          # (S, m, n) row stats in leaf order
     cand_leaf: jnp.ndarray,      # (m, L+1) bool
     num_leaves: int,
     impurity: str = "gini",
@@ -308,26 +307,26 @@ def best_numeric_split_leaf_ordered(
     """
     m, n = vals.shape
     L1 = num_leaves + 1
-    cnt = count_fn(task)
+    cnt = count_fn(task, axis=0)
     if row_counts is None:
         row_counts = jax.ops.segment_sum(
             jnp.ones((n,), jnp.int32), lf_pos, num_segments=L1)
 
-    contrib = jnp.where(inbag[..., None], stats, 0.0)
-    cum = jnp.cumsum(contrib, axis=1)
+    contrib = jnp.where(inbag[None], stats, 0.0)             # (S, m, n)
+    cum = jnp.cumsum(contrib, axis=2)
     cum_excl = cum - contrib
     is_start = jnp.concatenate(
         [jnp.ones((1,), bool), lf_pos[1:] != lf_pos[:-1]])   # (n,) shared
     start_idx = jax.lax.cummax(jnp.where(is_start, jnp.arange(n), -1))
-    left = cum_excl - cum_excl[:, start_idx, :]              # excl prefix
+    left = cum_excl - cum_excl[:, :, start_idx]              # excl prefix
     if totals is None:
-        flat = jnp.arange(m)[:, None] * L1 + lf_pos[None]
-        totals_cols = jax.ops.segment_sum(
-            contrib.reshape(m * n, -1), flat.reshape(-1),
-            num_segments=m * L1, indices_are_sorted=True).reshape(m, L1, -1)
-        parent = totals_cols[:, lf_pos, :]                   # (m, n, S)
+        flat = (jnp.arange(m)[:, None] * L1 + lf_pos[None]).reshape(-1)
+        totals_cols = jax.vmap(lambda c: jax.ops.segment_sum(
+            c.reshape(-1), flat, num_segments=m * L1,
+            indices_are_sorted=True))(contrib).reshape(-1, m, L1)
+        parent = totals_cols[:, :, lf_pos]                   # (S, m, n)
     else:
-        parent = totals[lf_pos][None]                        # shared (1,n,S)
+        parent = totals.T[:, lf_pos][:, None, :]             # shared (S,1,n)
     right = parent - left
 
     is_start_b = jnp.broadcast_to(is_start[None], (m, n))
@@ -340,7 +339,7 @@ def best_numeric_split_leaf_ordered(
     # impurity at a different array shape can flip the last ulp of
     # transcendentals (entropy's log), and the reference backend computes
     # it exactly this way
-    gain = jnp.where(ok, split_gain(left, right, impurity), NEG)
+    gain = jnp.where(ok, split_gain(left, right, impurity, axis=0), NEG)
     tau = (vals + pv) * 0.5
 
     # Materialize gain/tau before the log-depth scan: without the barrier
@@ -362,7 +361,7 @@ def best_numeric_split_leaf_ordered(
 # ---------------------------------------------------------------------------
 
 def best_numeric_split_histogram(
-    table: jnp.ndarray,          # (L+1, B, S) per-leaf (bin × stat) table
+    table: jnp.ndarray,          # (L+1, S, B) per-leaf (stat × bin) table
     cand_leaf: jnp.ndarray,      # (L+1,) bool
     impurity: str = "gini",
     task: str = "classification",
@@ -388,14 +387,15 @@ def best_numeric_split_histogram(
     edges[b]`).  Empty buckets (duplicate edges) give zero-gain duplicate
     cuts and are never selected over a populated boundary.
     """
-    totals = table.sum(1)                                   # (L+1, S)
-    cnt = count_fn(task)
-    prefix = jnp.cumsum(table, axis=1)                      # cut after bin b
-    left = prefix[:, :-1, :]                                # cuts 0..B-2
-    right = totals[:, None, :] - left
+    totals = table.sum(2)                                   # (L+1, S)
+    cnt = count_fn(task, axis=1)
+    prefix = jnp.cumsum(table, axis=2)                      # cut after bin b
+    left = prefix[:, :, :-1]                                # cuts 0..B-2
+    right = totals[:, :, None] - left
     ok = (cnt(left) >= min_records) & (cnt(right) >= min_records) \
         & cand_leaf[:, None]
-    gains = jnp.where(ok, split_gain(left, right, impurity), NEG)  # (L+1, B-1)
+    gains = jnp.where(ok, split_gain(left, right, impurity, axis=1),
+                      NEG)                                  # (L+1, B-1)
     best_cut = jnp.argmax(gains, axis=1)                    # first max
     best_gain = jnp.take_along_axis(gains, best_cut[:, None], axis=1)[:, 0]
     best_cut = jnp.where(jnp.isfinite(best_gain), best_cut, 0)
@@ -404,14 +404,15 @@ def best_numeric_split_histogram(
 
 def feature_count_tables(
     bin_of: jnp.ndarray,         # (m, n) packed bucket ids (uint8/uint16)
-    leaf_ids: jnp.ndarray,       # (n,) int32 scatter slots, 0 = discard
-    w: jnp.ndarray,              # (n,) float32 bag weights
-    stats: jnp.ndarray,          # (n, S) row stats
+    leaf_ids: jnp.ndarray,       # ([T,] n) int32 scatter slots, 0 = discard
+    w: jnp.ndarray,              # ([T,] n) float32 bag weights
+    stats: jnp.ndarray,          # ([T,] n, S) row stats
     num_slots: int,              # table width minus one (slots 1..num_slots)
     num_bins: int,
 ) -> jnp.ndarray:
-    """(m, num_slots+1, B, S) per-leaf bin tables for ALL m features in ONE
-    scatter over the flat (feature, slot, bin) index space.
+    """([T,] m, num_slots+1, S, B) per-leaf bin tables for ALL m features
+    in ONE scatter over the flat ([tree,] feature, slot, stat, bin) index
+    space.
 
     This is the jnp twin of the Pallas `feat_hist` kernel (kernels/ops
     .feature_tables): both accumulate each row's stat contribution into
@@ -423,20 +424,40 @@ def feature_count_tables(
 
     `leaf_ids` are pre-mapped scatter SLOTS, not necessarily raw leaf ids:
     the subtraction path (level/engines.py) passes the packed build-leaf
-    slots with derive-leaf rows mapped to the discarded slot 0.
+    slots with derive-leaf rows mapped to the discarded slot 0.  The
+    updates are one flat vector with rows minor, (T, m, S, n): see the
+    note on the stat axis above.
+
+    A leading tree axis T on `leaf_ids`/`w`/`stats` (the bins are shared)
+    folds into the same flat index space.  A vmap would give a scatter
+    with a batch dimension, whose compile time for a v5e grows with n:
+    91 s against 24 s flat for T = 4 at n = 2^18, and 414 s at 2^20.
     """
-    m, n = bin_of.shape
+    batched = leaf_ids.ndim == 2
+    if not batched:
+        leaf_ids, w, stats = leaf_ids[None], w[None], stats[None]
+    T, n = leaf_ids.shape
+    m = bin_of.shape[0]
     W = num_slots + 1
-    inbag = (w > 0) & (leaf_ids > 0)
-    contrib = jnp.where(inbag[:, None], stats, 0.0)          # (n, S)
-    base = leaf_ids.astype(jnp.int32) * num_bins + bin_of.astype(jnp.int32)
-    flat = (jnp.arange(m, dtype=jnp.int32)[:, None] * (W * num_bins)
-            + base)                                          # (m, n)
-    contrib_b = jnp.broadcast_to(contrib[None], (m, n, contrib.shape[-1]))
-    table = jax.ops.segment_sum(contrib_b.reshape(m * n, -1),
-                                flat.reshape(-1),
-                                num_segments=m * W * num_bins)
-    return table.reshape(m, W, num_bins, -1)
+    S = stats.shape[-1]
+    if T * m * W * S * num_bins >= 2 ** 31:   # int32 flat index overflow
+        return jax.vmap(lambda lf, ww, st: feature_count_tables(
+            bin_of, lf, ww, st, num_slots, num_bins))(leaf_ids, w, stats)
+    inbag = (w > 0) & (leaf_ids > 0)                        # (T, n)
+    contrib = jnp.where(inbag[:, None], jnp.swapaxes(stats, 1, 2),
+                        0.0)                                # (T, S, n)
+    slot_stat = (leaf_ids.astype(jnp.int32)[:, None] * S
+                 + jnp.arange(S, dtype=jnp.int32)[None, :, None])
+    tree_feat = (jnp.arange(T, dtype=jnp.int32)[:, None] * m
+                 + jnp.arange(m, dtype=jnp.int32)[None, :])  # (T, m)
+    flat = ((tree_feat[:, :, None, None] * (W * S)
+             + slot_stat[:, None]) * num_bins
+            + bin_of.astype(jnp.int32)[None, :, None, :])   # (T, m, S, n)
+    table = jax.ops.segment_sum(
+        jnp.broadcast_to(contrib[:, None], (T, m, S, n)).reshape(-1),
+        flat.reshape(-1), num_segments=T * m * W * S * num_bins)
+    table = table.reshape(T, m, W, S, num_bins)
+    return table if batched else table[0]
 
 
 # ---------------------------------------------------------------------------
@@ -451,17 +472,20 @@ def categorical_count_table(
     num_leaves: int,
     arity: int,
 ) -> jnp.ndarray:
-    """The paper's 'attribute value x class -> count' table, (L+1, V, S)."""
+    """The paper's 'attribute value x class -> count' table, (L+1, S, V)."""
     L1 = num_leaves + 1
+    S = stats.shape[-1]
     inbag = (w > 0) & (leaf_of > 0)
-    contrib = jnp.where(inbag[:, None], stats, 0.0)
-    flat = leaf_of * arity + x_col
-    table = jax.ops.segment_sum(contrib, flat, num_segments=L1 * arity)
-    return table.reshape(L1, arity, -1)
+    contrib = jnp.where(inbag[None], stats.T, 0.0)           # (S, n)
+    flat = ((leaf_of[None] * S + jnp.arange(S)[:, None]) * arity
+            + x_col[None])                                   # (S, n)
+    table = jax.ops.segment_sum(contrib.reshape(-1), flat.reshape(-1),
+                                num_segments=L1 * S * arity)
+    return table.reshape(L1, S, arity)
 
 
 def best_categorical_split_from_table(
-    table: jnp.ndarray,          # (L+1, V, S) per-leaf count table
+    table: jnp.ndarray,          # (L+1, S, V) per-leaf count table
     cand_leaf: jnp.ndarray,      # (L+1,) bool
     impurity: str = "gini",
     task: str = "classification",
@@ -470,11 +494,11 @@ def best_categorical_split_from_table(
     """Breiman ordering + ordered prefix cuts on a prebuilt count table.
 
     Shared scoring for the jnp path (`best_categorical_split`) and the
-    Pallas `cat_hist` kernel path (kernels/ops.categorical_tables) — the
+    Pallas table-kernel path (kernels/ops.categorical_tables) — the
     table layout is identical, so the two backends give identical splits.
 
     Args:
-      table:     (L+1, V, S) per-(leaf, category) stat sums — bag-weighted
+      table:     (L+1, S, V) per-(leaf, category) stat sums — bag-weighted
                  one-hot class counts (S = C, classification) or
                  [w, wy, wy²] (S = 3, regression).  Row 0 (the closed-leaf
                  sentinel) is ignored.  V may include padded categories;
@@ -494,33 +518,37 @@ def best_categorical_split_from_table(
     category to the LEFT child.  Under `tree.build_forest` this whole
     search is vmapped over a leading tree axis.
     """
-    arity = table.shape[1]
-    totals = table.sum(1)                                   # (L+1, S)
-    cnt = count_fn(task)
+    arity = table.shape[2]
+    totals = table.sum(2)                                   # (L+1, S)
+    cnt = count_fn(task, axis=1)
 
     tc = cnt(table)                                         # (L+1, V) counts
     if task == "classification":
-        metric = table[..., -1] / jnp.maximum(tc, 1e-12)
+        metric = table[:, -1] / jnp.maximum(tc, 1e-12)
     else:
-        metric = table[..., 1] / jnp.maximum(tc, 1e-12)
+        metric = table[:, 1] / jnp.maximum(tc, 1e-12)
     # Put empty categories last so cuts enumerate only populated prefixes.
     metric = jnp.where(tc > 0, metric, jnp.inf)
-    order = jnp.argsort(metric, axis=1)                     # (L+1, V)
-    sorted_table = jnp.take_along_axis(table, order[..., None], axis=1)
-    prefix = jnp.cumsum(sorted_table, axis=1)               # inclusive: cut after pos v
-    left = prefix[:, :-1, :]                                # cuts 0..V-2
-    right = totals[:, None, :] - left
+    order = jnp.argsort(metric, axis=1, stable=True)        # (L+1, V)
+    sorted_table = jnp.take_along_axis(table, order[:, None, :], axis=2)
+    prefix = jnp.cumsum(sorted_table, axis=2)               # inclusive: cut after pos v
+    left = prefix[:, :, :-1]                                # cuts 0..V-2
+    right = totals[:, :, None] - left
     ok = (cnt(left) >= min_records) & (cnt(right) >= min_records) \
         & cand_leaf[:, None]
-    gains = jnp.where(ok, split_gain(left, right, impurity), NEG)  # (L+1, V-1)
+    gains = jnp.where(ok, split_gain(left, right, impurity, axis=1),
+                      NEG)                                  # (L+1, V-1)
 
     best_cut = jnp.argmax(gains, axis=1)                    # first max: argmax picks first
     best_gain = jnp.take_along_axis(gains, best_cut[:, None], axis=1)[:, 0]
-    # mask in ordered space: positions <= cut; scatter back to category space
-    pos = jnp.arange(arity)[None, :]
-    in_left_sorted = pos <= best_cut[:, None]
-    mask = jnp.take_along_axis(
-        in_left_sorted, jnp.argsort(order, axis=1), axis=1)  # inverse perm
+    # category v goes left iff its sorted position is <= the cut.  The
+    # sort is stable, so positions follow (metric, category id) order:
+    # compare each category with the one at the cut instead of inverting
+    # the permutation (a second sort, slow to compile on a TPU)
+    at_cut = jnp.take_along_axis(order, best_cut[:, None], axis=1)   # (L+1, 1)
+    m_cut = jnp.take_along_axis(metric, at_cut, axis=1)
+    v = jnp.arange(arity)[None, :]
+    mask = (metric < m_cut) | ((metric == m_cut) & (v <= at_cut))
     return best_gain, mask
 
 

@@ -255,6 +255,15 @@ def _zeros_unless(cond, arr, dtype):
     return arr if cond else jnp.zeros((0, 0), dtype)
 
 
+def _level_num(num):
+    """The numeric columns as the level programs read them: feature-major
+    (m_num, n), materialized once per fit.  A TPU tiles an array's minor
+    axis in 128 lanes, so the row-major (n, m_num) layout pads a few
+    columns to 128 and lets the compiler lay the programs' (m_num, n)
+    intermediates out the same padded way."""
+    return jnp.asarray(num).T
+
+
 # ---------------------------------------------------------------------------
 # Host-side flat-tree bookkeeping (Alg. 2 step 8)
 # ---------------------------------------------------------------------------
@@ -423,7 +432,7 @@ def build_tree(
                      supersplit engine — "segment" (default; incrementally
                      maintained (leaf, value)-sorted layout, no per-level
                      sort), "scan" (faithful Alg. 1 sequential pass) or
-                     "kernel" (Pallas split_scan/cat_hist; interpret mode
+                     "kernel" (Pallas split_scan/feat_hist; interpret mode
                      off-TPU).
       seed/tree_idx: seeded bagging + candidate draws (paper §2.2) — all
                      randomness is a pure function of these two.
@@ -462,6 +471,7 @@ def build_tree(
     sorted_idx = jnp.asarray(sorted_idx)
     bin_of, bin_edges = _hist_state(num, sorted_vals, params, m_num,
                                     bin_of, bin_edges)
+    num = _level_num(num)
     # hist fast path: float edges stay HOST-side, decoding the reported
     # bin cuts into node thresholds (the level program reads only the
     # bit-packed bin cache); `carries` = the subtraction recurrence is on
@@ -688,6 +698,7 @@ def build_forest(
     # shared read-only input of the batched step, like the presorted order
     bin_of, bin_edges = _hist_state(num, sorted_vals, params, m_num,
                                     bin_of, bin_edges)
+    num = _level_num(num)
     carries = plan.carries_tables       # hist subtraction (DESIGN.md §6)
     edges_np = np.asarray(bin_edges) if plan.use_bin_cuts else None
     tidx = [int(t) for t in tree_indices]

@@ -1,22 +1,27 @@
-"""Pallas TPU kernel for multi-feature histogram tables (DESIGN.md §6).
+"""Pallas TPU kernel for per-leaf count tables (DESIGN.md §6, paper §2.4).
 
-`split_mode="hist"` builds, per depth level, a per-leaf (bin × stat) count
-table for EVERY drawn numeric candidate column.  The `cat_hist` kernel
-(which this generalizes) puts the feature index on the grid, so every
-feature re-reads the shared per-row state (leaf ids, bag weights, labels)
-— m× redundant HBM traffic for state that is identical across features.
-This kernel instead makes ONE pass over the row blocks: the per-row state
-and its stat contributions are loaded/computed once per block, and an
-inner loop over features accumulates each feature's one-hot transpose
-matmul (L1·Bv, Bn) @ (Bn, S) into a per-feature VMEM scratch slice.
+One kernel builds both table kinds the level step needs:
 
-The bin cache arrives BIT-PACKED (uint8 for <= 256 buckets, uint16 past —
-presort.bin_dtype), so the per-feature traffic is 1 byte per row instead
-of the 4 of the float32 column the exact engines read.  Like `cat_hist`,
-deep tables are tiled over a bucket-block grid dimension Bv so the VMEM
-scratch never exceeds m·L1·Bv·S floats; the histogram-subtraction path
-(level/engines.py) halves L1 by packing build leaves, which doubles the
-admissible Bv.
+  * `split_mode="hist"`: per-leaf (bin × stat) tables for EVERY numeric
+    column from the bit-packed bin cache (uint8 for <= 256 buckets, uint16
+    past — presort.bin_dtype), and
+  * exact categorical search: per-leaf (category × stat) tables, the
+    paper's "attribute value × class → number of records" count tables.
+
+A table entry is T[f, l, v, s] = Σ_rows [leaf = l][x_f = v] · stat_s, so
+per row block it factors into ONE matmul per feature,
+
+    (S·Wb, Bn) @ (Bn, Bv)ᵀ  =  (leaf-and-stat one-hot) · (value one-hot)ᵀ,
+
+with rows on the lane axis of both operands (the layout the row state has
+in HBM).  The leaf-and-stat operand is built once per row block and shared
+by every feature, so the per-row state (leaf ids, bag weights, labels) is
+read once per pass, not once per feature.  The output block stays resident
+in VMEM across the sequential row-block grid axis and is the accumulator.
+
+Deep or wide tables are tiled over a leaf-block and a value-block grid
+axis, sized by `block_plan` so the resident output block fits the VMEM
+budget; rows are re-read once per (leaf block, value block).
 """
 from __future__ import annotations
 
@@ -27,83 +32,98 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.cat_hist import _row_stats
+_VMEM_TABLE_BYTES = 8 << 20     # resident output block (double-buffered)
+_VMEM_LIMIT_BYTES = 48 << 20    # v5e has 128 MiB of VMEM; default scope 16
 
 
-def _feat_hist_kernel(x_ref, leaf_ref, w_ref, y_ref, out_ref, acc_scr, *,
-                      m, L1, bv, bn, nblocks, s_dim, task):
-    vb = pl.program_id(0)
-    jb = pl.program_id(1)
+def block_plan(W: int, V: int, m: int, s_dim: int) -> tuple[int, int]:
+    """(wb, bv): leaf-slot and value block sizes for an (m, W, S, V) table.
 
-    @pl.when(jb == 0)
+    bv is the whole value axis rounded up to 128 lanes while it is at most
+    512, else 512; wb is the largest multiple of 8 (at most W rounded up to
+    8) whose (m, S·wb, bv) f32 output block fits `_VMEM_TABLE_BYTES`.
+    """
+    vp = V + (-V) % 128
+    bv = vp if vp <= 512 else 512
+    per_slot = m * s_dim * bv * 4
+    wb = max(8, (_VMEM_TABLE_BYTES // per_slot) // 8 * 8)
+    return min(wb, W + (-W) % 8), bv
+
+
+def _hist_kernel(x_ref, leaf_ref, w_ref, y_ref, out_ref, *, m, wb, bv,
+                 s_dim, task):
+    lb = pl.program_id(0)
+    vb = pl.program_id(1)
+
+    @pl.when(pl.program_id(2) == 0)
     def _init():
-        acc_scr[...] = jnp.zeros((m * L1 * bv, s_dim), jnp.float32)
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.float32)
 
-    # shared per-row state: read and reduced ONCE per row block, reused by
-    # every feature (the cat_hist kernel re-reads these per feature)
-    leaf = leaf_ref[0, :].astype(jnp.int32)                   # (Bn,)
-    w = w_ref[0, :]
-    y = y_ref[0, :]
-    stats = _row_stats(y, w, s_dim, task)                     # (Bn, S)
-    inbag0 = (w > 0) & (leaf > 0)
-    v0 = vb * bv
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (bn, L1 * bv), 1)
-
-    def per_feature(f, carry):
-        x = pl.load(x_ref, (pl.ds(f, 1), slice(None)))[0].astype(jnp.int32)
-        in_range = (x >= v0) & (x < v0 + bv)
-        inbag = inbag0 & in_range
-        comb = leaf * bv + jnp.clip(x - v0, 0, bv - 1)        # (Bn,)
-        onehot = ((lanes == comb[:, None])
-                  & inbag[:, None]).astype(jnp.float32)
-        st = stats * inbag[:, None].astype(jnp.float32)
-        upd = jax.lax.dot(onehot.T, st,
-                          precision=jax.lax.Precision.HIGHEST)
-        rows = pl.ds(f * (L1 * bv), L1 * bv)
-        cur = pl.load(acc_scr, (rows, slice(None)))
-        pl.store(acc_scr, (rows, slice(None)), cur + upd)
-        return carry
-
-    jax.lax.fori_loop(0, m, per_feature, 0)
-
-    @pl.when(jb == nblocks - 1)
-    def _emit():
-        out_ref[...] = acc_scr[...].reshape(m, L1, bv, s_dim)
+    leaf = leaf_ref[...]                                  # (1, Bn) int32
+    w = w_ref[...]
+    y = y_ref[...]
+    bn = leaf.shape[1]
+    # operand rows r = s·wb + l: stat s, leaf slot lb·wb + l
+    r = jax.lax.broadcasted_iota(jnp.int32, (s_dim * wb, bn), 0)
+    srow = jnp.zeros_like(r)
+    for s in range(1, s_dim):
+        srow = srow + (r >= s * wb).astype(jnp.int32)
+    slot = r - srow * wb + lb * wb
+    hit = (slot == leaf) & (w > 0) & (leaf > 0)
+    if task == "classification":
+        a = jnp.where(hit & (srow == y.astype(jnp.int32)), w, 0.0)
+    else:
+        a = jnp.where(hit, jnp.where(srow == 0, w,
+                                     jnp.where(srow == 1, w * y, w * y * y)),
+                      0.0)
+    x = x_ref[...].astype(jnp.int32)                      # (m, Bn)
+    vals = jax.lax.broadcasted_iota(jnp.int32, (bv, bn), 0) + vb * bv
+    for f in range(m):
+        onehot = (vals == x[f:f + 1, :]).astype(jnp.float32)   # (Bv, Bn)
+        out_ref[f] += jax.lax.dot_general(
+            a, onehot, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
 
 
-def default_bv(V: int, L1: int, m: int) -> int:
-    """Bucket-block size keeping the VMEM scratch under ~m·L1·bv = 32k
-    floats per stat lane (the whole-feature-set analogue of cat_hist's
-    per-feature bound)."""
-    return min(V, max(1, (1 << 15) // max(1, L1 * max(m, 1))))
+@functools.partial(jax.jit, static_argnames=("W", "V", "s_dim", "bn", "task",
+                                             "interpret", "plan"))
+def feat_hist_pallas(x, leaf, w, y, *, W, V, s_dim, bn, task, interpret,
+                     plan=None):
+    """Count tables (m, W, S, V) for ALL m columns in one row pass.
 
-
-@functools.partial(jax.jit, static_argnames=("L1", "V", "s_dim", "bv", "bn",
-                                             "task", "interpret"))
-def feat_hist_pallas(x, leaf, w, y, *, L1, V, s_dim, bv=None, bn=256,
-                     task="classification", interpret=True):
-    """Histogram tables (m, L1, V, S) for ALL m features in one row pass.
-
-    x: (m, n) packed bucket ids (uint8/uint16); leaf/w/y: (n,) — shared
-    across features, NOT pre-broadcast.  V must be a multiple of bv and n
-    of bn; `kernels.ops.feature_tables` pads both for arbitrary shapes.
-    `leaf` entries are scatter SLOTS (0 = discard): the subtraction path
-    passes packed build-leaf slots, the plain path raw leaf ids.
+    x: (m, n) value ids (uint8/uint16 bins or int32 categories); leaf/w/y:
+    (n,) — shared across columns.  `leaf` entries are scatter SLOTS
+    (0 = discard): the subtraction path passes packed build-leaf slots,
+    the plain path raw leaf ids.  n must be a multiple of bn;
+    `kernels.ops.feature_tables` pads it.  `plan` = (wb, bv) overrides
+    `block_plan`, so small tests reach the multi-block tiling.
     """
     m, n = x.shape
-    bv = bv or default_bv(V, L1, m)
-    assert n % bn == 0 and V % bv == 0
-    grid = (V // bv, n // bn)
-    kernel = functools.partial(_feat_hist_kernel, m=m, L1=L1, bv=bv, bn=bn,
-                               nblocks=n // bn, s_dim=s_dim, task=task)
-    row_spec = pl.BlockSpec((1, bn), lambda v, j: (0, j))
-    return pl.pallas_call(
+    assert n % bn == 0, (n, bn)
+    wb, bv = plan or block_plan(W, V, m, s_dim)
+    Wp = W + (-W) % wb
+    Vp = V + (-V) % bv
+    grid = (Wp // wb, Vp // bv, n // bn)
+    kernel = functools.partial(_hist_kernel, m=m, wb=wb, bv=bv, s_dim=s_dim,
+                               task=task)
+    row_spec = pl.BlockSpec((1, bn), lambda l, v, j: (0, j))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((m, bn), lambda v, j: (0, j)),
+        in_specs=[pl.BlockSpec((m, bn), lambda l, v, j: (0, j)),
                   row_spec, row_spec, row_spec],
-        out_specs=pl.BlockSpec((m, L1, bv, s_dim), lambda v, j: (0, 0, v, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, L1, V, s_dim), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m * L1 * bv, s_dim), jnp.float32)],
+        out_specs=pl.BlockSpec((None, m, s_dim * wb, bv),
+                               lambda l, v, j: (l, 0, 0, v)),
+        out_shape=jax.ShapeDtypeStruct((Wp // wb, m, s_dim * wb, Vp),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(x, leaf[None], w[None], y[None])
+    )(x, leaf.astype(jnp.int32)[None], w.astype(jnp.float32)[None],
+      y.astype(jnp.float32)[None])
+    # (lb, m, s, l, v) -> (m, lb·wb + l, s, v)
+    out = out.reshape(Wp // wb, m, s_dim, wb, Vp)
+    out = out.transpose(1, 0, 3, 2, 4).reshape(m, Wp, s_dim, Vp)
+    return out[:, :W, :, :V]

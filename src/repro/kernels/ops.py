@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import splits
-from repro.kernels import cat_hist, feat_hist, split_scan
+from repro.kernels import feat_hist, split_scan
 
 
 def _on_tpu() -> bool:
@@ -122,92 +122,64 @@ def split_scan_supersplit(sorted_vals, sorted_idx, leaf_of, w, labels,
         w_g = jnp.pad(w_g, ((0, 0), (0, pad)))             # w 0 = skipped
         y_g = jnp.pad(y_g, ((0, 0), (0, pad)))
 
-    # global per-leaf totals per column (cheap; exact "right" histograms)
-    def tot(lf, ww, yy):
-        if task == "classification":
-            st = jax.nn.one_hot(yy.astype(jnp.int32), s_dim) * ww[:, None]
-        else:
-            st = jnp.stack([ww, ww * yy, ww * yy * yy], -1)
-        st = jnp.where(((ww > 0) & (lf > 0))[:, None], st, 0.0)
-        return jax.ops.segment_sum(st, lf, num_segments=L1)
-
-    totals = jax.vmap(tot)(leaf_g, w_g, y_g)          # (m, L1, S)
+    # global per-leaf totals (cheap; exact "right" histograms) — the same
+    # for every column, so reduced once over the unsorted rows
+    st = splits.row_stats(labels, w.astype(jnp.float32), s_dim, task)
+    st = jnp.where(((w > 0) & (leaf_of > 0))[:, None], st, 0.0)
+    totals = jnp.broadcast_to(
+        jax.ops.segment_sum(st, leaf_of, num_segments=L1), (m, L1, s_dim))
 
     return split_scan.split_scan_pallas(
-        sorted_vals, leaf_g, w_g, y_g, cand.astype(jnp.float32), totals,
-        L1=L1, s_dim=s_dim, bn=bn, impurity=impurity, task=task,
-        min_records=min_records, interpret=interpret)
+        sorted_vals, leaf_g.astype(jnp.int32), w_g.astype(jnp.float32), y_g,
+        cand.astype(jnp.float32), totals, L1=L1, s_dim=s_dim, bn=bn,
+        impurity=impurity, task=task, min_records=float(min_records),
+        interpret=interpret)
 
 
 def categorical_tables(cat_cols, leaf_of, w, labels, *, V, Lp,
-                       task="classification", bn=256, bv=None, interpret=None,
+                       task="classification", bn=256, interpret=None,
                        num_classes=None):
-    """Count tables (m_cat, Lp+1, V, S) via the Pallas cat_hist kernel.
+    """Count tables (m_cat, Lp+1, S, V) via the Pallas table kernel.
 
-    Arbitrary arity V is supported: the category axis is padded up to a
-    multiple of the kernel's category-block `bv` (values >= V never occur in
-    the data, so the padded lanes stay zero) and the result is sliced back.
+    Any arity V is supported: the kernel tiles the category axis (values
+    >= V never occur in the data, so padded lanes stay zero).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
-    m, n = cat_cols.shape
-    s_dim = _stat_dim(labels, num_classes, task)
-    if interpret:
-        # bound the unrolled row-block count (body work is linear in bn
-        # here — the one-hot matmul — so growing the block never gates)
-        bn, _, _ = _interpret_grid_plan(n, bn)
-    bv = bv or cat_hist.default_bv(V, Lp + 1)
-    Vp = V + (-V) % bv
-    pad = _pad_rows(n, bn)
-    leaf_b = jnp.broadcast_to(leaf_of, (m, n))
-    w_b = jnp.broadcast_to(w, (m, n))
-    y_b = jnp.broadcast_to(labels.astype(jnp.float32), (m, n))
-    if pad:
-        cat_cols = jnp.pad(cat_cols, ((0, 0), (0, pad)))
-        leaf_b = jnp.pad(leaf_b, ((0, 0), (0, pad)))
-        w_b = jnp.pad(w_b, ((0, 0), (0, pad)))
-        y_b = jnp.pad(y_b, ((0, 0), (0, pad)))
-    tables = cat_hist.cat_hist_pallas(
-        cat_cols, leaf_b, w_b, y_b, L1=Lp + 1, V=Vp, s_dim=s_dim, bv=bv,
-        bn=bn, task=task, interpret=interpret)
-    return tables[:, :, :V, :] if Vp != V else tables
+    return _tables(cat_cols, leaf_of, w, labels, W=Lp + 1, V=V, task=task,
+                   bn=bn, interpret=interpret, num_classes=num_classes)
 
 
 def feature_tables(bin_of, leaf_ids, w, labels, *, B, W,
-                   task="classification", bn=256, bv=None, interpret=None,
+                   task="classification", bn=256, interpret=None,
                    num_classes=None):
-    """Histogram tables (m, W, B, S) for ALL features in ONE pass over the
-    row blocks, via the Pallas `feat_hist` kernel.
+    """Histogram tables (m, W, S, B) for ALL features in ONE pass over the
+    row blocks, via the Pallas table kernel (`feat_hist`).
 
     bin_of: (m, n) bit-packed bucket ids; leaf_ids: (n,) scatter slots
     (0 = discard; raw leaf ids on the plain path, packed build slots on
     the subtraction path — see level/engines.py); W = slot-axis width.
-    The jnp twin is `splits.feature_count_tables` (one flat segment_sum)
-    — same accumulation order, so backends agree (bit-identically for the
-    integer classification stats).  Arbitrary B is supported by padding
-    the bucket axis to the kernel's bucket block `bv` and slicing back.
+    The jnp twin is `splits.feature_count_tables` (one flat segment_sum);
+    the classification stats are integers, so the two agree bit for bit.
     """
+    return _tables(bin_of, leaf_ids, w, labels, W=W, V=B, task=task, bn=bn,
+                   interpret=interpret, num_classes=num_classes)
+
+
+def _tables(x, leaf, w, labels, *, W, V, task, bn, interpret, num_classes):
     if interpret is None:
         interpret = not _on_tpu()
-    m, n = bin_of.shape
+    m, n = x.shape
     s_dim = _stat_dim(labels, num_classes, task)
     if interpret:
         # bound the unrolled row-block count (body work per block is
         # linear in bn — the per-feature one-hot matmuls — so growing the
         # block never gates)
         bn, _, _ = _interpret_grid_plan(n, bn)
-    bv = bv or feat_hist.default_bv(B, W, m)
-    Bp = B + (-B) % bv
     pad = _pad_rows(n, bn)
-    leaf = leaf_ids.astype(jnp.int32)
-    wv = w
     y = labels.astype(jnp.float32)
     if pad:
-        bin_of = jnp.pad(bin_of, ((0, 0), (0, pad)))   # bin 0, but leaf 0 =
-        leaf = jnp.pad(leaf, (0, pad))                 # discarded anyway
-        wv = jnp.pad(wv, (0, pad))                     # w 0 = skipped
+        x = jnp.pad(x, ((0, 0), (0, pad)))       # value 0, but leaf 0 =
+        leaf = jnp.pad(leaf, (0, pad))           # discarded anyway
+        w = jnp.pad(w, (0, pad))                 # w 0 = skipped
         y = jnp.pad(y, (0, pad))
-    tables = feat_hist.feat_hist_pallas(
-        bin_of, leaf, wv, y, L1=W, V=Bp, s_dim=s_dim, bv=bv, bn=bn,
-        task=task, interpret=interpret)
-    return tables[:, :, :B, :] if Bp != B else tables
+    return feat_hist.feat_hist_pallas(x, leaf, w, y, W=W, V=V, s_dim=s_dim,
+                                      bn=bn, task=task, interpret=interpret)

@@ -35,12 +35,13 @@ def split_scan_ref(vals, leaf, w, y, cand, totals, *, L1, s_dim,
 
 @functools.partial(jax.jit, static_argnames=("L1", "V", "s_dim", "task"))
 def cat_hist_ref(x, leaf, w, y, *, L1, V, s_dim, task="classification"):
-    """Count table (m, L1, V, S) — one pass per column."""
+    """Count table (m, L1, S, V) — one pass per column."""
     def col(xc, lf, ww, yy):
         stats = splits.row_stats(yy, ww, s_dim, task)
         inbag = (ww > 0) & (lf > 0)
         contrib = jnp.where(inbag[:, None], stats, 0.0)
         flat = lf * V + xc
-        return jax.ops.segment_sum(contrib, flat, num_segments=L1 * V).reshape(L1, V, s_dim)
+        return jax.ops.segment_sum(contrib, flat, num_segments=L1 * V).reshape(
+            L1, V, s_dim).transpose(0, 2, 1)
 
     return jax.vmap(col)(x, leaf, w, y)

@@ -11,13 +11,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax >= 0.5 wants explicit axis_types; 0.4.x has neither the kwarg nor
-    # jax.sharding.AxisType — fall back to the plain call there
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,6 +21,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _make_mesh(shape, axes)
 
 
-def make_host_mesh(data: int = 2, model: int = 4):
-    """Small mesh over forced host devices, for distributed-engine tests."""
+def make_host_mesh(data: int | None = None, model: int | None = None):
+    """data × model mesh over this process's devices (forced host devices
+    in the distributed-engine tests, the chips of one host otherwise).
+    Without arguments it spans every device: 2 × k when the count is even
+    (eight host devices: 2 × 4; four chips: 2 × 2), else 1 × k."""
+    if data is None:
+        count = len(jax.devices())
+        data = 2 if count % 2 == 0 else 1
+        model = count // data
     return _make_mesh((data, model), ("data", "model"))

@@ -19,6 +19,11 @@ Two modes, picked automatically:
     sharded-hist forest is bit-identical to the local reference and to
     every other process (fingerprints compared by the launcher).
 
+The workers run on the CPU backend only (the launcher sets
+``JAX_PLATFORMS=cpu`` for them): N processes cannot share one chip, and a
+worker refuses any other backend.  On a TPU host, the sharded engines run
+in ONE process over the chips present (``chip_smoke.py --chips 4``).
+
 Run:  python -m repro.launch.multihost_smoke [--nproc N]
 Test: tests/test_multihost_smoke.py (-m slow).
 
@@ -74,6 +79,12 @@ def _train(mesh) -> tuple[str, object]:
 
 def worker(pid: int, nproc: int) -> None:
     import jax
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"multihost_smoke workers run on forced CPU host devices, not "
+            f"{jax.default_backend()!r}: N processes cannot share a chip. "
+            f"Run the sharded engines in one process on the chips "
+            f"present instead (chip_smoke.py --chips 4).")
     jax.distributed.initialize(
         coordinator_address=f"localhost:{_PORT}",
         num_processes=nproc, process_id=pid)
@@ -104,7 +115,7 @@ def worker(pid: int, nproc: int) -> None:
 
 def main(nproc: int = 2, timeout: float = 900.0) -> dict:
     """Spawn the workers, collect and validate their output."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count="
                          f"{_DEVS_PER_PROC}")
     procs = [subprocess.Popen(

@@ -42,7 +42,9 @@ def _assert_identical(ta, tb, ctx=""):
 
 @pytest.fixture(scope="module")
 def mixed_ds():
-    rng = np.random.default_rng(3)
+    # seed chosen so the 4 trees below finish at different depths under
+    # the installed jax's bagging draws (one tree closes at depth 3)
+    rng = np.random.default_rng(10)
     n = 1100
     num = rng.normal(size=(n, 4)).astype(np.float32)
     cat = rng.integers(0, 5, size=(n, 2)).astype(np.int32)

@@ -8,7 +8,7 @@ from repro.core import forest as forest_lib
 from repro.core import presort, splits, tree as tree_lib
 from repro.core.dataset import from_numpy
 from repro.core.forest import RandomForest
-from repro.kernels import ops as kops
+from repro.kernels import feat_hist, ops as kops
 
 
 def _build_both(ds, params, seed=5, tree_idx=0, supersplit_fn=None):
@@ -93,7 +93,7 @@ def test_fused_matches_reference_deeper_multiclass():
 
 
 # ---------------------------------------------------------------------------
-# Pallas cat_hist-backed categorical supersplit vs the jnp reference
+# Pallas table-kernel categorical supersplit vs the jnp reference
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("V,bv", [
@@ -116,8 +116,15 @@ def test_kernel_categorical_path_matches_reference(V, bv):
 
     tables = kops.categorical_tables(
         jnp.asarray(x.T), jnp.asarray(leaf), jnp.asarray(w),
-        jnp.asarray(y), V=V, Lp=L, bv=bv, num_classes=C)
-    assert tables.shape == (m, L + 1, V, C)
+        jnp.asarray(y), V=V, Lp=L, num_classes=C)
+    assert tables.shape == (m, L + 1, C, V)
+    # the same tables with the value axis tiled in bv-sized blocks (and 2
+    # leaf slots per block), so a padded last value block is exercised
+    tiled = feat_hist.feat_hist_pallas(
+        jnp.asarray(x.T), jnp.asarray(leaf), jnp.asarray(w),
+        jnp.asarray(y.astype(np.float32)), W=L + 1, V=V, s_dim=C, bn=128,
+        task="classification", interpret=True, plan=(2, bv))
+    np.testing.assert_array_equal(np.asarray(tiled), np.asarray(tables))
     for j in range(m):
         g_k, m_k = splits.best_categorical_split_from_table(
             tables[j], jnp.asarray(cand[j]))

@@ -72,8 +72,8 @@ def test_hist_scorer_matches_numpy_bruteforce():
     table[2] = 0.0                                # empty leaf
     edges = np.sort(rng.normal(size=B)).astype(np.float32)
     cand = np.array([False] + [True] * L)
-    g, t = splits.best_numeric_split_histogram(
-        jnp.asarray(table), jnp.asarray(cand))
+    g, t = splits.best_numeric_split_histogram(    # scorer layout (L+1, S, B)
+        jnp.asarray(table.transpose(0, 2, 1)), jnp.asarray(cand))
     g, t = np.asarray(g), np.asarray(t)
     tb = table.astype(np.float64)
     for h in range(1, L + 1):
@@ -164,7 +164,7 @@ def test_hist_batched_matches_per_tree(mixed_ds, backend):
     with uneven finish depths exercising the early-finish masking
     (satellite of the exact-mode contract in tests/test_forest_batch.py).
     The kernel backend routes the bucket tables through the Pallas
-    cat_hist kernel with bins as the arity."""
+    feat_hist kernel."""
     kw = _build_kw(mixed_ds)
     p = tree_lib.TreeParams(max_depth=5, min_records=60, backend=backend,
                             split_mode="hist", num_bins=8)

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import splits
-from repro.kernels import cat_hist, ops, ref
+from repro.kernels import feat_hist, ops, ref
 
 
 def _mk(seed, n, m, L, C, dup=False):
@@ -108,11 +108,12 @@ def test_cat_hist_kernel_sweep(V, bv, bn):
     leaf = rng.integers(0, L + 1, n).astype(np.int32)
     w = rng.integers(0, 3, n).astype(np.float32)
     y = rng.integers(0, C, n).astype(np.int32)
-    tbl_k = cat_hist.cat_hist_pallas(
-        jnp.asarray(x), jnp.asarray(np.broadcast_to(leaf, (m, n))),
-        jnp.asarray(np.broadcast_to(w, (m, n))),
-        jnp.asarray(np.broadcast_to(y.astype(np.float32), (m, n))),
-        L1=L + 1, V=V, s_dim=C, bv=bv, bn=bn, interpret=True)
+    # wb=2 leaf slots and bv values per block: every tiling axis has
+    # several blocks, and V % bv != 0 pads the last value block
+    tbl_k = feat_hist.feat_hist_pallas(
+        jnp.asarray(x), jnp.asarray(leaf), jnp.asarray(w),
+        jnp.asarray(y.astype(np.float32)), W=L + 1, V=V, s_dim=C, bn=bn,
+        task="classification", interpret=True, plan=(2, bv))
     tbl_r = ref.cat_hist_ref(
         jnp.asarray(x), jnp.asarray(np.broadcast_to(leaf, (m, n))),
         jnp.asarray(np.broadcast_to(w, (m, n))),
@@ -180,7 +181,7 @@ def test_split_scan_gated_fallback_matches_kernel(monkeypatch):
 
 
 def test_cat_hist_chunked_blocks_exact(monkeypatch):
-    """cat_hist block growth is exact (integer scatter-adds, order-free)."""
+    """Categorical table block growth is exact (integer sums, order-free)."""
     n, m, L, C, V = 700, 2, 3, 2, 9
     rng = np.random.default_rng(1)
     x = rng.integers(0, V, size=(m, n)).astype(np.int32)

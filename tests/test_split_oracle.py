@@ -152,7 +152,7 @@ def _engine_supersplit(backend, num, leaf, w, y, C, Lp, impurity,
         row_counts = np.bincount(lf_pos, minlength=Lp + 1).astype(np.int32)
         g, t = splits.best_numeric_split_leaf_ordered(
             jnp.asarray(vals), jnp.asarray(lf_pos), jnp.asarray(inbag),
-            stats[jnp.asarray(ord_idx)], jnp.asarray(cand), Lp, impurity,
+            stats.T[:, jnp.asarray(ord_idx)], jnp.asarray(cand), Lp, impurity,
             task, min_records, totals=None,
             row_counts=jnp.asarray(row_counts))
         return np.asarray(g), np.asarray(t)
@@ -267,7 +267,7 @@ def test_categorical_table_scorer_binary_exhaustive():
         cand = jnp.asarray([False] + [True] * L)
         g, mask = splits.best_categorical_split_from_table(table, cand)
         g, mask = np.asarray(g), np.asarray(mask)
-        tb = np.asarray(table, np.float64)
+        tb = np.asarray(table, np.float64).transpose(0, 2, 1)   # (L+1, V, S)
         for h in range(1, L + 1):
             total = tb[h].sum(0)
             best = -np.inf
@@ -330,7 +330,7 @@ def test_hist_scorer_on_streamed_tables_matches_oracle():
         bins = presort.bin_block(num, edges)               # (m, n)
         stats = splits.row_stats(jnp.asarray(y), jnp.asarray(w), C,
                                  "classification")
-        table = np.zeros((m, L + 1, B, C), np.float32)
+        table = np.zeros((m, L + 1, C, B), np.float32)
         for lo in range(0, n, 83):                         # uneven tail
             hi = min(lo + 83, n)
             table += np.asarray(splits.feature_count_tables(
