@@ -37,6 +37,7 @@ def measure(mesh, n: int, n_trees: int, depth: int) -> list[dict]:
 
     import numpy as np
 
+    from repro import obs
     from repro.core import distributed, tree as tree_lib
     from repro.core.dataset import from_numpy
     from repro.core.forest import RandomForest
@@ -51,15 +52,16 @@ def measure(mesh, n: int, n_trees: int, depth: int) -> list[dict]:
                      tree_batch=tree_batch).fit(ds, engine=engine)  # warm
         best, rf, programs = float('inf'), None, 0
         for rep in (1, 2):
-            c0 = (tree_lib._STEP_CALLS[0], tree_lib._BATCH_STEP_CALLS[0])
+            c0 = (obs.counter("level.tree_dispatches"),
+                  obs.counter("level.dispatches"))
             t0 = time.perf_counter()
             out = RandomForest(params, num_trees=n_trees, seed=10,
                                tree_batch=tree_batch).fit(ds, engine=engine)
             dt = time.perf_counter() - t0
             if rep == 1:
                 rf = out
-                programs = (tree_lib._STEP_CALLS[0] - c0[0]
-                            + tree_lib._BATCH_STEP_CALLS[0] - c0[1])
+                programs = (obs.counter("level.tree_dispatches") - c0[0]
+                            + obs.counter("level.dispatches") - c0[1])
             best = min(best, dt)
         return best, rf, programs
 

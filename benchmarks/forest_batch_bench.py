@@ -31,6 +31,7 @@ OUT_PATH = os.environ.get("BENCH_FOREST_BATCH_JSON", "BENCH_forest_batch.json")
 def _fit_seconds(ds, params, n_trees, tree_batch, seed):
     """One warm fit (compile) + best-of-2 timed fits; returns (s, forest,
     level-program dispatches per timed fit)."""
+    from repro import obs
     from repro.core import tree as tree_lib
     from repro.core.forest import RandomForest
 
@@ -40,15 +41,16 @@ def _fit_seconds(ds, params, n_trees, tree_batch, seed):
                  tree_batch=tree_batch).fit(ds)              # warm jits
     best, forest, programs = float("inf"), None, 0
     for rep in (1, 2):
-        c0 = (tree_lib._STEP_CALLS[0], tree_lib._BATCH_STEP_CALLS[0])
+        c0 = (obs.counter("level.tree_dispatches"),
+              obs.counter("level.dispatches"))
         t0 = time.perf_counter()
         rf = RandomForest(params, num_trees=n_trees, seed=seed,
                           tree_batch=tree_batch).fit(ds)
         dt = time.perf_counter() - t0
         if rep == 1:
             forest = rf          # for the cross-path parity check
-            programs = (tree_lib._STEP_CALLS[0] - c0[0]
-                        + tree_lib._BATCH_STEP_CALLS[0] - c0[1])
+            programs = (obs.counter("level.tree_dispatches") - c0[0]
+                        + obs.counter("level.dispatches") - c0[1])
         best = min(best, dt)
     return best, forest, programs
 

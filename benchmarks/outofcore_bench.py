@@ -69,11 +69,10 @@ def _labels_for(chunks, n):
 def _bench_point(n, trees, depth, chunk, workdir, with_checkpoint=False):
     import numpy as np
 
-    from repro.core import checkpoint as ckpt_mod
+    from repro import obs
     from repro.core import tree as tree_lib
     from repro.core.dataset import MemmapRowSource
     from repro.core.forest import RandomForest
-    from repro.core.level import plan as plan_mod
 
     chunks = _chunk_gen(n, chunk, SEED)
     y = _labels_for(chunks, n)
@@ -87,13 +86,13 @@ def _bench_point(n, trees, depth, chunk, workdir, with_checkpoint=False):
 
     params = tree_lib.TreeParams(max_depth=depth, split_mode="hist",
                                  num_bins=NUM_BINS, bagging="none")
-    c0 = plan_mod._STREAM_CHUNK_CALLS[0]
-    t1 = plan_mod._STREAM_CHUNK_TRACES[0]
+    c0 = obs.counter("stream.chunk_dispatches")
+    t1 = obs.counter("stream.traces")
     t0 = time.perf_counter()
     RandomForest(params=params, num_trees=trees, seed=3).fit_streamed(src)
     fit_s = time.perf_counter() - t0
-    calls = plan_mod._STREAM_CHUNK_CALLS[0] - c0
-    traces = plan_mod._STREAM_CHUNK_TRACES[0] - t1
+    calls = obs.counter("stream.chunk_dispatches") - c0
+    traces = obs.counter("stream.traces") - t1
 
     rss_mb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
     rows_per_sec = n * trees / fit_s
@@ -111,16 +110,16 @@ def _bench_point(n, trees, depth, chunk, workdir, with_checkpoint=False):
     if with_checkpoint:
         # Same fit with per-level snapshots flushed to disk.  Overhead is
         # reported as the fraction of the checkpointed wall spent inside
-        # checkpoint writes (CKPT_WALL times every manifest/trees/snapshot
+        # checkpoint writes (`ckpt.write_s` times every manifest/trees/snapshot
         # write), which is far less noisy on a loaded box than the ratio
         # of two independently-measured walls.
         ckdir = os.path.join(workdir, f"ck_{n}")
-        w0 = ckpt_mod.CKPT_WALL[0]
+        w0 = obs.counter("ckpt.write_s")
         t0 = time.perf_counter()
         RandomForest(params=params, num_trees=trees, seed=3).fit_streamed(
             src, checkpoint_dir=ckdir, checkpoint_every=1)
         fit_ckpt_s = time.perf_counter() - t0
-        ckpt_write_s = ckpt_mod.CKPT_WALL[0] - w0
+        ckpt_write_s = obs.counter("ckpt.write_s") - w0
         frac = ckpt_write_s / fit_ckpt_s
         emit(f"outofcore/fit_ckpt/n{n}", fit_ckpt_s * 1e6,
              f"ckpt_write={ckpt_write_s:.3f}s;overhead_frac={frac:.4f}")

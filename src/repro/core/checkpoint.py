@@ -33,6 +33,7 @@ snapshot holds replicated host state, so one copy is enough.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import json
@@ -42,14 +43,10 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import atomicio
 
 FORMAT_VERSION = 1
-
-# Wall-clock seconds spent inside checkpoint writes (snapshots, trees,
-# manifests).  benchmarks/outofcore_bench.py reads the delta around a
-# checkpointed fit to gate the overhead fraction (<= 5%).
-CKPT_WALL = [0.0]
 
 # Test hook (repro.testing.faults): called after each level snapshot
 # lands on disk, with (depth, path) — armed to SIGKILL at a chosen
@@ -186,14 +183,24 @@ def _unpack_trees(z) -> tuple[list, list]:
     return trees, unpack_stats(z["stats_json"])
 
 
+@contextlib.contextmanager
+def _write_timer():
+    """A checkpoint write: the span `repro.ckpt.write` and its seconds in
+    the counter `ckpt.write_s` (benchmarks/outofcore_bench.py gates the
+    overhead fraction on the latter)."""
+    t0 = time.perf_counter()
+    with obs.span("repro.ckpt.write"):
+        yield
+    obs.count("ckpt.write_s", time.perf_counter() - t0)
+
+
 def _save_npz(path: str, arrays: dict) -> None:
     # uncompressed on purpose: checkpoints are transient (deleted at batch
     # commit) and written on the fit's critical path — zlib costs ~9x the
     # raw write and buys nothing we keep
-    t0 = time.perf_counter()
-    atomicio.atomic_replace(
-        path, lambda tmp: np.savez(open(tmp, "wb"), **arrays))
-    CKPT_WALL[0] += time.perf_counter() - t0
+    with _write_timer():
+        atomicio.atomic_replace(
+            path, lambda tmp: np.savez(open(tmp, "wb"), **arrays))
 
 
 def _shrink_ids(a: np.ndarray) -> np.ndarray:
@@ -357,9 +364,8 @@ class StreamCheckpointer:
     def _write_manifest(self) -> None:
         if not self.is_writer:
             return
-        t0 = time.perf_counter()
-        atomicio.atomic_write_json(self._manifest_path(), self._manifest)
-        CKPT_WALL[0] += time.perf_counter() - t0
+        with _write_timer():
+            atomicio.atomic_write_json(self._manifest_path(), self._manifest)
 
     # -- completed batches ---------------------------------------------
     def load_batch(self, tidx) -> Optional[tuple[list, list]]:

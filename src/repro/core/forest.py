@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import bagging, presort, tree as tree_lib
 from repro.core.dataset import TabularDataset
 from repro.core.level.engines import SplitEngine
@@ -156,14 +157,11 @@ def pack_trees(trees: list) -> PackedForest:
         m_num=trees[0].m_num, iters=iters)
 
 
-# trace counter: tests assert predict_proba compiles ONCE for a whole
-# forest (no per-tree retraces) — the body below runs only at trace time
-_PREDICT_TRACES = [0]
-
-
 def _forest_predict_impl(feature, threshold, is_cat, cat_mask, children,
                          value, num, cat, m_num, iters, reduce_mean):
-    _PREDICT_TRACES[0] += 1
+    # runs only at trace time: tests assert predict_proba compiles ONCE
+    # for a whole forest (no per-tree retraces)
+    obs.count("predict.traces")
     B = num.shape[0] if num.size else cat.shape[0]
 
     def one_tree(f, th, ic, cm, ch, val):
@@ -284,13 +282,19 @@ class RandomForest:
                 "fit() trains from a fully materialized TabularDataset; "
                 "for a RowSource (out-of-core bin cache) use "
                 "fit_streamed(source)")
+        with obs.span("repro.fit", trees=self.num_trees):
+            return self._fit(ds, collect_stats, supersplit_fn, engine,
+                             cat_engine)
+
+    def _fit(self, ds, collect_stats, supersplit_fn, engine, cat_engine):
         ds.validate()
         self.num_classes = ds.num_classes
         self.m, self.m_num = ds.m, ds.m_num
         # §2.1 dataset preparation: presort once, reuse for every tree.
         if ds.m_num:
-            sorted_idx = presort.presort_columns(ds.num)
-            sorted_vals = presort.gather_sorted(ds.num, sorted_idx)
+            with obs.span("repro.fit.presort"):
+                sorted_idx = presort.presort_columns(ds.num)
+                sorted_vals = presort.gather_sorted(ds.num, sorted_idx)
         else:
             sorted_idx = jnp.zeros((0, ds.n), jnp.int32)
             sorted_vals = jnp.zeros((0, ds.n), jnp.float32)
@@ -303,8 +307,9 @@ class RandomForest:
         if self.params.split_mode == "hist" and ds.m_num:
             # hist mode: quantize once per forest (the PLANET-style fixed
             # bucket budget), shared by every tree/level like the presort
-            bin_of, bin_edges = presort.quantize(ds.num, sorted_vals,
-                                                 self.params.num_bins)
+            with obs.span("repro.fit.quantize"):
+                bin_of, bin_edges = presort.quantize(ds.num, sorted_vals,
+                                                     self.params.num_bins)
             kw.update(bin_of=bin_of, bin_edges=bin_edges)
         if supersplit_fn is not None and engine is not None:
             raise ValueError(
@@ -321,23 +326,26 @@ class RandomForest:
                 "(tree_batch=1, one level program per depth PER TREE); "
                 "pass a repro.core.level SplitEngine (engine=...) to keep "
                 "the batched one-program-per-depth path",
-                UserWarning, stacklevel=2)
+                UserWarning, stacklevel=3)   # the caller of fit()
             tb = 1                      # per-tree-only configuration
         self.trees, self.level_stats = [], []
         if tb > 1:
             for lo in range(0, self.num_trees, tb):
-                trees, stats = tree_lib.build_forest(
-                    tree_indices=range(lo, min(lo + tb, self.num_trees)),
-                    **kw)
+                hi = min(lo + tb, self.num_trees)
+                with obs.span("repro.forest.batch", batch=f"{lo}:{hi}"):
+                    trees, stats = tree_lib.build_forest(
+                        tree_indices=range(lo, hi), **kw)
                 self.trees.extend(trees)
                 self.level_stats.extend(stats)
         else:
             for t in range(self.num_trees):
-                tr, stats = tree_lib.build_tree(
-                    tree_idx=t, supersplit_fn=supersplit_fn, **kw)
+                with obs.span("repro.forest.batch", batch=f"{t}:{t + 1}"):
+                    tr, stats = tree_lib.build_tree(
+                        tree_idx=t, supersplit_fn=supersplit_fn, **kw)
                 self.trees.append(tr)
                 self.level_stats.append(stats)
-        self.packed = pack_trees(self.trees)      # stacked inference arrays
+        with obs.span("repro.forest.pack"):
+            self.packed = pack_trees(self.trees)  # stacked inference arrays
         return self
 
     def fit_streamed(self, source, collect_stats: bool = False,
@@ -386,15 +394,17 @@ class RandomForest:
               if self.tree_batch is not None else min(self.num_trees, 16))
         self.trees, self.level_stats = [], []
         for lo in range(0, self.num_trees, tb):
-            trees, stats = tree_lib.build_forest_streamed(
-                source=source,
-                tree_indices=range(lo, min(lo + tb, self.num_trees)),
-                params=self.params, seed=self.seed,
-                collect_stats=collect_stats, engine=engine,
-                resume=resume, _checkpointer=ck)
+            hi = min(lo + tb, self.num_trees)
+            with obs.span("repro.forest.batch", batch=f"{lo}:{hi}"):
+                trees, stats = tree_lib.build_forest_streamed(
+                    source=source, tree_indices=range(lo, hi),
+                    params=self.params, seed=self.seed,
+                    collect_stats=collect_stats, engine=engine,
+                    resume=resume, _checkpointer=ck)
             self.trees.extend(trees)
             self.level_stats.extend(stats)
-        self.packed = pack_trees(self.trees)
+        with obs.span("repro.forest.pack"):
+            self.packed = pack_trees(self.trees)
         return self
 
     # ------------------------------------------------------------------

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import forest as forest_lib
 from repro.core import presort, tree as tree_lib
 from repro.core.dataset import TabularDataset
@@ -45,11 +46,6 @@ class GBTParams:
     seed: int = 0
 
 
-# trace counter: tests assert predict_raw compiles ONCE for a whole model
-# (no per-round retraces) — mirrors forest._PREDICT_TRACES
-_RAW_TRACES = [0]
-
-
 @functools.partial(jax.jit, static_argnames=("m_num", "iters"))
 def _gbt_predict_raw_jit(feature, threshold, is_cat, cat_mask, children,
                          value, num, cat, base_score, learning_rate,
@@ -60,7 +56,9 @@ def _gbt_predict_raw_jit(feature, threshold, is_cat, cat_mask, children,
     over the round axis of the packed arrays); the scaled reduction over
     rounds stays inside the same jit.
     """
-    _RAW_TRACES[0] += 1
+    # runs only at trace time: tests assert predict_raw compiles ONCE for a
+    # whole model (no per-round retraces)
+    obs.count("gbt.raw_traces")
     preds = forest_lib._forest_predict_impl(
         feature, threshold, is_cat, cat_mask, children, value, num, cat,
         m_num, iters, reduce_mean=False)                     # (T, B, 1)

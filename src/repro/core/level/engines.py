@@ -326,18 +326,22 @@ class HistNumeric(SplitEngine):
 
     def supersplits(self, inp, st, Lp, cand):
         Wb = Lp // 2 + 1 if st.subtract else Lp + 1
-        bins, slots, w, stats, labels = _hist_build_rows(
-            inp, st.subtract, compact=True)
-        packed = self._tables(inp, st, Wb, bins, slots, w, stats, labels)
-        if st.subtract:
-            tables = _expand_subtracted(packed, inp.prev_tables,
-                                        inp.parent_of, inp.sib_of,
-                                        inp.slot_of)
-        else:
-            tables = packed
-        g, c = jax.vmap(
-            lambda tb, cd: splits.best_numeric_split_histogram(
-                tb, cd, st.impurity, st.task, st.min_records))(tables, cand)
+        with jax.named_scope("level.supersplit.tables"):
+            bins, slots, w, stats, labels = _hist_build_rows(
+                inp, st.subtract, compact=True)
+            packed = self._tables(inp, st, Wb, bins, slots, w, stats,
+                                  labels)
+            if st.subtract:
+                tables = _expand_subtracted(packed, inp.prev_tables,
+                                            inp.parent_of, inp.sib_of,
+                                            inp.slot_of)
+            else:
+                tables = packed
+        with jax.named_scope("level.supersplit.score"):
+            g, c = jax.vmap(
+                lambda tb, cd: splits.best_numeric_split_histogram(
+                    tb, cd, st.impurity, st.task, st.min_records))(
+                tables, cand)
         if st.carry_tables:
             return g, c, tables
         return g, c

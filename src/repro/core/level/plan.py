@@ -31,22 +31,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import bagging, splits
 from repro.core.level.engines import (CategoricalTable, ExactNumeric,
                                       HistNumeric, LevelInputs, LevelStatics,
                                       SplitEngine)
 
-# Dispatch/trace counters: tests assert the batched builder issues ONE
-# jitted level program per depth per tree-batch (and never falls back to
-# per-tree dispatches).  CALLS bump at dispatch time (in the tree.py
-# drivers), TRACES at trace time.  tree.py re-exports these lists (same
-# objects) under the historical names.
-_STEP_CALLS = [0]          # per-tree fused level dispatches (build_tree)
-_BATCH_STEP_CALLS = [0]    # batched level dispatches (build_forest)
-_BATCH_STEP_TRACES = [0]   # distinct compilations of the batched program
-_STREAM_CHUNK_CALLS = [0]  # streamed per-chunk dispatches (build_forest_streamed)
-_STREAM_CHUNK_TRACES = [0]  # distinct compilations of the chunk program
-_STREAM_SCORE_TRACES = [0]  # distinct compilations of the stream scorer
+# Dispatch and trace counts live in `repro.obs`: the tree.py drivers
+# count dispatches (`level.dispatches`, `level.tree_dispatches`,
+# `stream.chunk_dispatches`), the programs below count their traces
+# (`level.traces`, `stream.traces`, `stream.score_traces`).  Phases of the
+# programs carry `jax.named_scope` names (`level.draw`, ...; see obs).
 
 # Above this many row-state elements (T·m_num·n) the batched level step
 # switches from vmap (SIMD across trees) to lax.map (sequential trees, one
@@ -358,7 +353,8 @@ def _level_step_core(num, cat, labels, sorted_vals, sorted_idx, bin_of,
     n = leaf_of.shape[0]
 
     # Alg. 2 step 3: seeded per-leaf candidate features (paper §2.2/§2.4)
-    cand_p = _candidates(fkey, depth, splittable_p, Lp, plan)
+    with jax.named_scope("level.draw"):
+        cand_p = _candidates(fkey, depth, splittable_p, Lp, plan)
 
     inp = LevelInputs(num=num, cat=cat, labels=labels,
                       sorted_vals=sorted_vals, sorted_idx=sorted_idx,
@@ -372,60 +368,65 @@ def _level_step_core(num, cat, labels, sorted_vals, sorted_idx, bin_of,
     gains_parts, masks = [], None
     new_tables = pre_tables
     thr_num = jnp.zeros((max(m_num, 1), L1), jnp.float32)
-    if m_num:
-        if pre_num is not None:
-            g, t = pre_num
-        else:
-            res = plan.numeric.supersplits(inp, statics, Lp,
-                                           cand_p[:, :m_num].T)
-            if carries:
-                g, t, new_tables = res
+    with jax.named_scope("level.supersplit"):
+        if m_num:
+            if pre_num is not None:
+                g, t = pre_num
             else:
-                g, t = res
-        gains_parts.append(g)
-        thr_num = t
-    if m_cat:
-        if pre_cat is not None:
-            g, masks = pre_cat
+                res = plan.numeric.supersplits(inp, statics, Lp,
+                                               cand_p[:, :m_num].T)
+                if carries:
+                    g, t, new_tables = res
+                else:
+                    g, t = res
+            gains_parts.append(g)
+            thr_num = t
+        if m_cat:
+            if pre_cat is not None:
+                g, masks = pre_cat
+            else:
+                g, masks = plan.categorical.supersplits(inp, statics, Lp,
+                                                        cand_p[:, m_num:].T)
+            gains_parts.append(g)
+
+    with jax.named_scope("level.merge"):
+        all_gains = jnp.concatenate(gains_parts, axis=0)        # (m, L1)
+
+        # tree builder merges partial supersplits (Alg. 2 step 3, argmax)
+        best_feat = jnp.argmax(all_gains, axis=0).astype(jnp.int32)
+        best_gain = jnp.take_along_axis(all_gains, best_feat[None], 0)[0]
+        will_split = (splittable_p & jnp.isfinite(best_gain)
+                      & (best_gain > 1e-9))
+
+        # children get consecutive 1-based ids in leaf order (step 6)
+        ks = jnp.cumsum(will_split.astype(jnp.int32))
+        new_left = jnp.where(will_split, 2 * ks - 1, 0).astype(jnp.int32)
+        new_right = jnp.where(will_split, 2 * ks, 0).astype(jnp.int32)
+
+        feat_of_leaf = jnp.where(will_split, best_feat, 0).astype(jnp.int32)
+        iscat_of_leaf = will_split & (best_feat >= m_num) if m_cat else \
+            jnp.zeros((L1,), bool)
+        thr_sel = jnp.take_along_axis(
+            thr_num, jnp.clip(best_feat, 0, max(m_num - 1, 0))[None], 0)[0]
+        thr_of_leaf = jnp.where(will_split & ~iscat_of_leaf, thr_sel, 0.0)
+        if m_cat:
+            jc = jnp.clip(best_feat - m_num, 0, m_cat - 1)
+            mask_sel = masks[jc, jnp.arange(L1)]                # (L1, V)
+            mask_of_leaf = jnp.where(iscat_of_leaf[:, None], mask_sel,
+                                     False)
         else:
-            g, masks = plan.categorical.supersplits(inp, statics, Lp,
-                                                    cand_p[:, m_num:].T)
-        gains_parts.append(g)
-
-    all_gains = jnp.concatenate(gains_parts, axis=0)            # (m, L1)
-
-    # tree builder merges partial supersplits (Alg. 2 step 3, final argmax)
-    best_feat = jnp.argmax(all_gains, axis=0).astype(jnp.int32)  # (L1,)
-    best_gain = jnp.take_along_axis(all_gains, best_feat[None], 0)[0]
-    will_split = splittable_p & jnp.isfinite(best_gain) & (best_gain > 1e-9)
-
-    # children get consecutive 1-based ids in leaf order (Alg. 2 step 6)
-    ks = jnp.cumsum(will_split.astype(jnp.int32))
-    new_left = jnp.where(will_split, 2 * ks - 1, 0).astype(jnp.int32)
-    new_right = jnp.where(will_split, 2 * ks, 0).astype(jnp.int32)
-
-    feat_of_leaf = jnp.where(will_split, best_feat, 0).astype(jnp.int32)
-    iscat_of_leaf = will_split & (best_feat >= m_num) if m_cat else \
-        jnp.zeros((L1,), bool)
-    thr_sel = jnp.take_along_axis(
-        thr_num, jnp.clip(best_feat, 0, max(m_num - 1, 0))[None], 0)[0]
-    thr_of_leaf = jnp.where(will_split & ~iscat_of_leaf, thr_sel, 0.0)
-    if m_cat:
-        jc = jnp.clip(best_feat - m_num, 0, m_cat - 1)
-        mask_sel = masks[jc, jnp.arange(L1)]                    # (L1, V)
-        mask_of_leaf = jnp.where(iscat_of_leaf[:, None], mask_sel, False)
-    else:
-        mask_of_leaf = jnp.zeros((L1, plan.max_arity), bool)
+            mask_of_leaf = jnp.zeros((L1, plan.max_arity), bool)
 
     # Alg. 2 steps 5-6: 1-bit condition per sample, reassign to children
-    bits = _eval_conditions_core(num, cat, leaf_of, feat_of_leaf,
-                                 thr_of_leaf, iscat_of_leaf, mask_of_leaf,
-                                 m_num,
-                                 bin_of=bin_of if plan.use_bin_cuts
-                                 else None)
-    new_leaf_of = jnp.where(
-        leaf_of > 0,
-        jnp.where(bits, new_left[leaf_of], new_right[leaf_of]), 0)
+    with jax.named_scope("level.reassign"):
+        bits = _eval_conditions_core(num, cat, leaf_of, feat_of_leaf,
+                                     thr_of_leaf, iscat_of_leaf,
+                                     mask_of_leaf, m_num,
+                                     bin_of=bin_of if plan.use_bin_cuts
+                                     else None)
+        new_leaf_of = jnp.where(
+            leaf_of > 0,
+            jnp.where(bits, new_left[leaf_of], new_right[leaf_of]), 0)
 
     use_ord = plan.use_ord
     struct = {"best_feat": best_feat, "best_gain": best_gain,
@@ -441,26 +442,27 @@ def _level_step_core(num, cat, labels, sorted_vals, sorted_idx, bin_of,
         return struct, new_leaf_of, ord_idx, None, part, new_tables
 
     # next-level totals (node values / counts / splittable for depth+1)
-    inb = (w > 0) & (new_leaf_of > 0)
-    next_totals = jax.ops.segment_sum(jnp.where(inb[:, None], stats, 0.0),
-                                      new_leaf_of, num_segments=2 * Lp + 1)
+    with jax.named_scope("level.totals"):
+        inb = (w > 0) & (new_leaf_of > 0)
+        next_totals = jax.ops.segment_sum(
+            jnp.where(inb[:, None], stats, 0.0), new_leaf_of,
+            num_segments=2 * Lp + 1)
 
-    if use_ord or carries:
-        # next level's per-child row counts: the ord layout's row_counts,
-        # and (subtraction) what the host uses to pick each split's
-        # SMALLER child as the build leaf
-        key_counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32),
-                                         new_leaf_of, num_segments=2 * Lp + 1)
-        struct["key_counts"] = key_counts
-    if use_ord:
-        if need_partition:
+        if use_ord or carries:
+            # next level's per-child row counts: the ord layout's
+            # row_counts, and (subtraction) what the host uses to pick
+            # each split's SMALLER child as the build leaf
+            key_counts = jax.ops.segment_sum(
+                jnp.ones((n,), jnp.int32), new_leaf_of,
+                num_segments=2 * Lp + 1)
+            struct["key_counts"] = key_counts
+    if use_ord and need_partition:
+        with jax.named_scope("level.partition"):
             lf_pos = leaf_of[ord_idx[0]]
             new_ord_idx = _partition_leaf_order(
                 ord_idx, lf_pos, bits, new_left, new_right, row_counts,
                 key_counts)
-        else:       # the next level cannot split again (max depth reached)
-            new_ord_idx = ord_idx
-    else:
+    else:   # no ord layout, or the next level cannot split (max depth)
         new_ord_idx = ord_idx
     return struct, new_leaf_of, new_ord_idx, next_totals, None, new_tables
 
@@ -475,6 +477,7 @@ def _fused_level_step(num, cat, labels, sorted_vals, sorted_idx, bin_of,
                       slot_of, fkey, depth, *, plan, Lp, need_partition,
                       subtract=False):
     """The per-tree fused level step (see `_level_step_core`)."""
+    obs.count("level.traces")
     struct, new_leaf_of, new_ord_idx, next_totals, _, new_tables = \
         _level_step_core(
             num, cat, labels, sorted_vals, sorted_idx, bin_of, bin_edges,
@@ -540,7 +543,7 @@ def _fused_level_step_batched(num, cat, labels, sorted_vals, sorted_idx,
     Returns the per-tree struct dict and next-level state, all with the
     leading T axis; the host fetches the structs in ONE transfer per level.
     """
-    _BATCH_STEP_TRACES[0] += 1
+    obs.count("level.traces")
     T, n = leaf_of.shape
     m_num, m_cat = plan.m_num, plan.m_cat
     use_ord = plan.use_ord
@@ -552,7 +555,9 @@ def _fused_level_step_batched(num, cat, labels, sorted_vals, sorted_idx,
     has_pre_num = bool(m_num) and plan.numeric.batch_native
     has_pre_cat = bool(m_cat) and plan.categorical.batch_native
     if has_pre_num or has_pre_cat:
-        cand_b = _candidates_batched(fkeys, depth, splittable_p, Lp, plan)
+        with jax.named_scope("level.draw"):
+            cand_b = _candidates_batched(fkeys, depth, splittable_p, Lp,
+                                         plan)
         inp_b = LevelInputs(num=num, cat=cat, labels=labels,
                             sorted_vals=sorted_vals, sorted_idx=sorted_idx,
                             bin_of=bin_of, bin_edges=bin_edges,
@@ -563,16 +568,17 @@ def _fused_level_step_batched(num, cat, labels, sorted_vals, sorted_idx,
                             slot_of=slot_of)
         statics_b = plan.statics._replace(carry_tables=carries,
                                           subtract=subtract)
-        if has_pre_num:
-            res = plan.numeric.supersplits_batched(
-                inp_b, statics_b, Lp, cand_b[:, :m_num])
-            if carries:
-                pre_tables = res[2]      # carried OUTSIDE the tree vmap
-                res = res[:2]
-            pres += list(res)
-        if has_pre_cat:
-            pres += list(plan.categorical.supersplits_batched(
-                inp_b, statics_b, Lp, cand_b[:, m_num:]))
+        with jax.named_scope("level.supersplit"):
+            if has_pre_num:
+                res = plan.numeric.supersplits_batched(
+                    inp_b, statics_b, Lp, cand_b[:, :m_num])
+                if carries:
+                    pre_tables = res[2]  # carried OUTSIDE the tree vmap
+                    res = res[:2]
+                pres += list(res)
+            if has_pre_cat:
+                pres += list(plan.categorical.supersplits_batched(
+                    inp_b, statics_b, Lp, cand_b[:, m_num:]))
 
     def _unpack_pre(rest):
         pn = pc = None
@@ -596,18 +602,21 @@ def _fused_level_step_batched(num, cat, labels, sorted_vals, sorted_idx,
                 fused_tail=True, pre_num=pn, pre_cat=pc)
             return s, nl, no, nt, ntab
 
-        struct, new_leaf_of, new_ord_idx, next_totals, new_tables = \
-            jax.lax.map(
-                body, tuple([ord_idx, leaf_of, w, stats, splittable_p,
-                             totals, row_counts, prev_tables, parent_of,
-                             sib_of, slot_of, fkeys] + pres))
+        # the loop's own slicing and stacking of each tree's state
+        with jax.named_scope("level.tree_loop"):
+            struct, new_leaf_of, new_ord_idx, next_totals, new_tables = \
+                jax.lax.map(
+                    body, tuple([ord_idx, leaf_of, w, stats, splittable_p,
+                                 totals, row_counts, prev_tables, parent_of,
+                                 sib_of, slot_of, fkeys] + pres))
         if pre_tables is not None:
             new_tables = pre_tables
         # rows closed in EVERY tree: the (free) batched-pruning trigger —
         # the driver reads it from the fetched struct instead of issuing a
         # separate reduction + host sync per level
-        struct = dict(struct, closed_rows=jnp.sum(
-            ~(new_leaf_of > 0).any(axis=0)))
+        with jax.named_scope("level.totals"):
+            struct = dict(struct, closed_rows=jnp.sum(
+                ~(new_leaf_of > 0).any(axis=0)))
         return struct, new_leaf_of, new_ord_idx, next_totals, new_tables
 
     def vcore(num, cat, labels, sorted_vals, sorted_idx, bin_of, bin_edges,
@@ -634,29 +643,29 @@ def _fused_level_step_batched(num, cat, labels, sorted_vals, sorted_idx,
     # results are bit-identical (each tree's rows accumulate in the same
     # order as in the per-tree program) but the scatters lower ~2x faster
     # than their vmapped form on CPU
-    struct = dict(struct, closed_rows=jnp.sum(      # see the map branch
-        ~(new_leaf_of > 0).any(axis=0)))
-    L2 = 2 * Lp + 1
-    flat_ids = (new_leaf_of
-                + jnp.arange(T, dtype=jnp.int32)[:, None] * L2).reshape(-1)
-    inb = (w > 0) & (new_leaf_of > 0)
-    next_totals = jax.ops.segment_sum(
-        jnp.where(inb.reshape(-1)[:, None], stats.reshape(T * n, -1), 0.0),
-        flat_ids, num_segments=T * L2).reshape(T, L2, -1)
-    if use_ord or carries:
-        key_counts = jax.ops.segment_sum(
-            jnp.ones((T * n,), jnp.int32), flat_ids,
-            num_segments=T * L2).reshape(T, L2)
-        struct = dict(struct, key_counts=key_counts)
-    if use_ord:
-        if need_partition:
+    with jax.named_scope("level.totals"):
+        struct = dict(struct, closed_rows=jnp.sum(  # see the map branch
+            ~(new_leaf_of > 0).any(axis=0)))
+        L2 = 2 * Lp + 1
+        flat_ids = (new_leaf_of + jnp.arange(T, dtype=jnp.int32)[:, None]
+                    * L2).reshape(-1)
+        inb = (w > 0) & (new_leaf_of > 0)
+        next_totals = jax.ops.segment_sum(
+            jnp.where(inb.reshape(-1)[:, None], stats.reshape(T * n, -1),
+                      0.0),
+            flat_ids, num_segments=T * L2).reshape(T, L2, -1)
+        if use_ord or carries:
+            key_counts = jax.ops.segment_sum(
+                jnp.ones((T * n,), jnp.int32), flat_ids,
+                num_segments=T * L2).reshape(T, L2)
+            struct = dict(struct, key_counts=key_counts)
+    if use_ord and need_partition:
+        with jax.named_scope("level.partition"):
             bits, new_left, new_right = part
             lf_pos = jax.vmap(lambda lf, oi: lf[oi])(leaf_of, ord_idx[:, 0])
             new_ord_idx = _partition_leaf_order(
                 ord_idx, lf_pos, bits, new_left, new_right, row_counts,
                 key_counts)
-        else:
-            new_ord_idx = ord_idx
     else:
         new_ord_idx = ord_idx
     return struct, new_leaf_of, new_ord_idx, next_totals, new_tables
@@ -706,7 +715,7 @@ def _stream_chunk_step(bins_c, labels_c, w_c, leaf_prev_c, feat_of_leaf,
     assignment — and the updated accumulator).  Padding rows ride with
     w = 0 and leaf_prev = 0: they stay closed and contribute zero.
     """
-    _STREAM_CHUNK_TRACES[0] += 1
+    obs.count("stream.traces")
     c = labels_c.shape[0]
     statics = plan.statics
 
@@ -718,14 +727,16 @@ def _stream_chunk_step(bins_c, labels_c, w_c, leaf_prev_c, feat_of_leaf,
             xbin = bins_c[jn, jnp.arange(c)].astype(jnp.int32)
             bit = xbin <= cut[lf].astype(jnp.int32)
             return jnp.where(lf > 0, jnp.where(bit, nl[lf], nr[lf]), 0)
-        leaf_c = jax.vmap(reassign)(leaf_prev_c, feat_of_leaf, cut_of_leaf,
-                                    new_left, new_right)
+        with jax.named_scope("level.reassign"):
+            leaf_c = jax.vmap(reassign)(leaf_prev_c, feat_of_leaf,
+                                        cut_of_leaf, new_left, new_right)
 
     stats_c = jax.vmap(lambda ww: splits.row_stats(
         labels_c, ww, plan.num_classes, plan.task))(w_c)
     if need_tables:
-        tables = plan.numeric.stream_accumulate(
-            tables, bins_c, leaf_c, w_c, stats_c, labels_c, statics, Lp)
+        with jax.named_scope("level.supersplit.tables"):
+            tables = plan.numeric.stream_accumulate(
+                tables, bins_c, leaf_c, w_c, stats_c, labels_c, statics, Lp)
     else:
         # final level: no more splits to score — accumulate only the
         # per-leaf stat totals (T, Lp+1, S) for the node values
@@ -733,7 +744,8 @@ def _stream_chunk_step(bins_c, labels_c, w_c, leaf_prev_c, feat_of_leaf,
             inb = (ww > 0) & (lf > 0)
             return jax.ops.segment_sum(jnp.where(inb[:, None], stt, 0.0),
                                        lf, num_segments=Lp + 1)
-        tables = tables + jax.vmap(tot)(leaf_c, w_c, stats_c)
+        with jax.named_scope("level.totals"):
+            tables = tables + jax.vmap(tot)(leaf_c, w_c, stats_c)
     return leaf_c, tables
 
 
@@ -757,24 +769,29 @@ def _stream_score_step(tables, splittable_p, fkeys, depth, *, plan, Lp):
     Returns the per-tree decision struct; `thr` holds winning BIN INDICES
     (plan.use_bin_cuts) and `new_left`/`new_right`/`feat_of_leaf` feed the
     next level's chunk reassignment."""
-    _STREAM_SCORE_TRACES[0] += 1
+    obs.count("stream.score_traces")
 
     def per_tree(tb, sp, fk):
-        cand_p = _candidates(fk, depth, sp, Lp, plan)           # (L+1, m)
-        g, cuts = jax.vmap(
-            lambda t, cd: splits.best_numeric_split_histogram(
-                t, cd, plan.impurity, plan.task, plan.min_records))(
-            tb, cand_p[:, :plan.m_num].T)
-        best_feat = jnp.argmax(g, axis=0).astype(jnp.int32)
-        best_gain = jnp.take_along_axis(g, best_feat[None], 0)[0]
-        will_split = sp & jnp.isfinite(best_gain) & (best_gain > 1e-9)
-        ks = jnp.cumsum(will_split.astype(jnp.int32))
-        new_left = jnp.where(will_split, 2 * ks - 1, 0).astype(jnp.int32)
-        new_right = jnp.where(will_split, 2 * ks, 0).astype(jnp.int32)
-        feat_of_leaf = jnp.where(will_split, best_feat, 0).astype(jnp.int32)
-        thr_sel = jnp.take_along_axis(
-            cuts, jnp.clip(best_feat, 0, max(plan.m_num - 1, 0))[None], 0)[0]
-        thr_of_leaf = jnp.where(will_split, thr_sel, 0.0)
+        with jax.named_scope("level.draw"):
+            cand_p = _candidates(fk, depth, sp, Lp, plan)       # (L+1, m)
+        with jax.named_scope("level.supersplit.score"):
+            g, cuts = jax.vmap(
+                lambda t, cd: splits.best_numeric_split_histogram(
+                    t, cd, plan.impurity, plan.task, plan.min_records))(
+                tb, cand_p[:, :plan.m_num].T)
+        with jax.named_scope("level.merge"):
+            best_feat = jnp.argmax(g, axis=0).astype(jnp.int32)
+            best_gain = jnp.take_along_axis(g, best_feat[None], 0)[0]
+            will_split = sp & jnp.isfinite(best_gain) & (best_gain > 1e-9)
+            ks = jnp.cumsum(will_split.astype(jnp.int32))
+            new_left = jnp.where(will_split, 2 * ks - 1, 0).astype(jnp.int32)
+            new_right = jnp.where(will_split, 2 * ks, 0).astype(jnp.int32)
+            feat_of_leaf = jnp.where(will_split, best_feat,
+                                     0).astype(jnp.int32)
+            thr_sel = jnp.take_along_axis(
+                cuts, jnp.clip(best_feat, 0, max(plan.m_num - 1, 0))[None],
+                0)[0]
+            thr_of_leaf = jnp.where(will_split, thr_sel, 0.0)
         return {"best_feat": best_feat, "best_gain": best_gain,
                 "thr": thr_of_leaf, "will_split": will_split,
                 "new_left": new_left, "new_right": new_right,

@@ -277,29 +277,33 @@ class ShardedHistNumeric(_MeshEngine):
             # parent/sib/slot (T, L+1)) when subtracting
             # NO row compaction here: the build-rows <= n/2 bound is
             # global, not per row shard — derive rows mask to slot 0
-            if subtract:
-                prev, par, sib, slot = sub
-                ids = jax.vmap(lambda sl, l: sl[l])(slot, lf)
-            else:
-                ids = lf
-            packed = splits.feature_count_tables(bo, ids, ww, stt, Wb - 1, B)
-            if R is not None:
-                # THE merge: one psum of the (T, m_loc, Wb, S, B) tables —
-                # under subtraction only build slots cross the network
-                packed = jax.lax.psum(packed, R)
-            if subtract:
-                tables = jax.vmap(
-                    lambda pk, pv, pr, sb, sl:
-                    _expand_subtracted(pk, pv, pr, sb, sl))(
-                        packed, prev, par, sib, slot)
-            else:
-                tables = packed
+            with jax.named_scope("level.supersplit.tables"):
+                if subtract:
+                    prev, par, sib, slot = sub
+                    ids = jax.vmap(lambda sl, l: sl[l])(slot, lf)
+                else:
+                    ids = lf
+                packed = splits.feature_count_tables(bo, ids, ww, stt,
+                                                     Wb - 1, B)
+                if R is not None:
+                    # THE merge: one psum of the (T, m_loc, Wb, S, B)
+                    # tables — under subtraction only build slots cross
+                    # the network
+                    packed = jax.lax.psum(packed, R)
+                if subtract:
+                    tables = jax.vmap(
+                        lambda pk, pv, pr, sb, sl:
+                        _expand_subtracted(pk, pv, pr, sb, sl))(
+                            packed, prev, par, sib, slot)
+                else:
+                    tables = packed
 
             def score(tb_t, cl_t):
                 return jax.vmap(
                     lambda tb, c: splits.best_numeric_split_histogram(
                         tb, c, impurity, task, min_records))(tb_t, cl_t)
-            g, cuts = jax.vmap(score)(tables, cl)
+            with jax.named_scope("level.supersplit.score"):
+                g, cuts = jax.vmap(score)(tables, cl)
             if st.carry_tables:
                 return g, cuts, tables
             return g, cuts

@@ -96,7 +96,8 @@ def bin_columns(num: jnp.ndarray, edges: jnp.ndarray) -> jnp.ndarray:
 
     def per_col(v, e):
         return jnp.searchsorted(e[:-1], v, side="left").astype(dt)
-    return jax.vmap(per_col)(num.T, edges)
+    with jax.named_scope("presort.bin_columns"):
+        return jax.vmap(per_col)(num.T, edges)
 
 
 def quantize(num: jnp.ndarray, sorted_vals: jnp.ndarray,

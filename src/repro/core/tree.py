@@ -43,10 +43,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import bagging, class_list, presort, pruning, splits
 from repro.core.level.engines import LegacyFn, SplitEngine
-from repro.core.level.plan import (_BATCH_STEP_CALLS, _BATCH_STEP_TRACES,
-                                   _BATCH_VMAP_ELEMS_DEFAULT, _STEP_CALLS,
+from repro.core.level.plan import (_BATCH_VMAP_ELEMS_DEFAULT,
                                    _fused_level_step,
                                    _fused_level_step_batched, _leaf_totals,
                                    _pad_leaves, make_plan)
@@ -253,6 +253,18 @@ def _make_plan(params, *, sorted_vals, arities, labels, num_classes,
 
 def _zeros_unless(cond, arr, dtype):
     return arr if cond else jnp.zeros((0, 0), dtype)
+
+
+def _start_fetch(tree) -> None:
+    """Start the non-blocking D2H transfer of every device array."""
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if hasattr(leaf, "copy_to_host_async"):
+            leaf.copy_to_host_async()
+
+
+def _nbytes(tree) -> int:
+    """Bytes of the (host) arrays in a pytree: what a fetch brought back."""
+    return sum(np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(tree))
 
 
 def _level_num(num):
@@ -504,96 +516,107 @@ def build_tree(
     no_map = jnp.zeros((0,), jnp.int32)
     totals_np = None
     row_counts_np = None
+    batch = f"{tree_idx}:{tree_idx + 1}"     # the tree, as a span arg
     for depth in range(params.max_depth + 1):
         L = len(open_nodes)
         if L == 0:
             break
         Lp = _pad_leaves(L, params.leaf_pad)
 
-        # leaf totals -> node values & forced closes (carried over from the
-        # previous level's fused step; computed once at the root)
-        if totals_np is None:
-            totals_np = np.asarray(_leaf_totals(leaf_of, stats, w, Lp))
-            row_counts_np = np.zeros(Lp + 1, np.int32)
-            row_counts_np[1] = n
-        else:
-            cur = np.zeros((Lp + 1, totals_np.shape[1]), np.float32)
-            cur[:L + 1] = totals_np[:L + 1]
-            totals_np = cur
-            cur_rc = np.zeros(Lp + 1, np.int32)
-            k = min(L + 1, len(row_counts_np))   # only threaded if use_ord
-            cur_rc[:k] = row_counts_np[:k]
-            row_counts_np = cur_rc
-        counts = cnt_np(totals_np)
-        for h, node in enumerate(open_nodes, start=1):
-            acc.set_value(node, totals_np[h], counts[h], task)
+        with obs.span("repro.level.prep", depth=depth, batch=batch):
+            # leaf totals -> node values & forced closes (carried over from
+            # the previous level's fused step; computed once at the root)
+            if totals_np is None:
+                with obs.span("repro.level.fetch", depth=depth, batch=batch):
+                    # waits for the presort / quantize programs too
+                    totals_np = np.asarray(_leaf_totals(leaf_of, stats, w,
+                                                        Lp))
+                row_counts_np = np.zeros(Lp + 1, np.int32)
+                row_counts_np[1] = n
+            else:
+                cur = np.zeros((Lp + 1, totals_np.shape[1]), np.float32)
+                cur[:L + 1] = totals_np[:L + 1]
+                totals_np = cur
+                cur_rc = np.zeros(Lp + 1, np.int32)
+                k = min(L + 1, len(row_counts_np))  # threaded if use_ord
+                cur_rc[:k] = row_counts_np[:k]
+                row_counts_np = cur_rc
+            counts = cnt_np(totals_np)
+            for h, node in enumerate(open_nodes, start=1):
+                acc.set_value(node, totals_np[h], counts[h], task)
 
-        at_max_depth = depth >= params.max_depth
-        splittable = np.array(
-            [counts[h] >= 2 * params.min_records and not at_max_depth
-             for h in range(1, L + 1)] + [False] * (Lp - L))
-        if not splittable.any():
-            break
-        splittable_p = np.concatenate([[False], splittable])
+            at_max_depth = depth >= params.max_depth
+            splittable = np.array(
+                [counts[h] >= 2 * params.min_records and not at_max_depth
+                 for h in range(1, L + 1)] + [False] * (Lp - L))
+            if not splittable.any():
+                break
+            splittable_p = np.concatenate([[False], splittable])
 
-        # histogram subtraction: relate this frontier to the carried
-        # previous-level tables (maps live on the host — tiny per-leaf
-        # int arrays — and ride up with the other level inputs)
-        subtract = bool(carries and tables is not None
-                        and maps_src is not None)
-        if subtract:
-            parent_np, sib_np, slot_np = _child_maps(*maps_src, Lp)
-            maps_dev = (tables, jnp.asarray(parent_np),
-                        jnp.asarray(sib_np), jnp.asarray(slot_np))
-        else:
-            maps_dev = (no_tables, no_map, no_map, no_map)
+            # histogram subtraction: relate this frontier to the carried
+            # previous-level tables (maps live on the host — tiny per-leaf
+            # int arrays — and ride up with the other level inputs)
+            subtract = bool(carries and tables is not None
+                            and maps_src is not None)
+            if subtract:
+                parent_np, sib_np, slot_np = _child_maps(*maps_src, Lp)
+                maps_dev = (tables, jnp.asarray(parent_np),
+                            jnp.asarray(sib_np), jnp.asarray(slot_np))
+            else:
+                maps_dev = (no_tables, no_map, no_map, no_map)
 
         # the whole level on device: one dispatch, one small struct back
-        _STEP_CALLS[0] += 1
-        struct, leaf_of, ord_idx, next_totals, new_tables = \
-            _fused_level_step(
-                _zeros_unless(plan.pass_num or not hist, num, jnp.float32),
-                cat, labels,
-                _zeros_unless(plan.pass_sorted, sorted_vals, jnp.float32),
-                _zeros_unless(plan.pass_sorted, sorted_idx, jnp.int32),
-                bin_of,
-                _zeros_unless(plan.pass_edges or not hist, bin_edges,
-                              jnp.float32),
-                ord_idx, leaf_of, w, stats,
-                jnp.asarray(splittable_p), jnp.asarray(totals_np),
-                jnp.asarray(row_counts_np), *maps_dev, fkey,
-                jnp.int32(depth), plan=plan, Lp=Lp,
-                need_partition=use_ord and depth + 1 < params.max_depth,
-                subtract=subtract)
-        if carries:
-            tables = new_tables
-        # non-blocking D2H of the small per-level struct
-        for leaf in jax.tree_util.tree_leaves((struct, next_totals)):
-            if hasattr(leaf, "copy_to_host_async"):
-                leaf.copy_to_host_async()
-        host, totals_np = jax.device_get((struct, next_totals))
-        if use_ord or carries:
-            row_counts_np = host["key_counts"]
-        if carries:
-            maps_src = (host["will_split"], host["key_counts"], L)
+        obs.count("level.tree_dispatches")
+        obs.count("level.tree_rows", n)
+        with obs.span("repro.level.dispatch", depth=depth, batch=batch):
+            struct, leaf_of, ord_idx, next_totals, new_tables = \
+                _fused_level_step(
+                    _zeros_unless(plan.pass_num or not hist, num,
+                                  jnp.float32),
+                    cat, labels,
+                    _zeros_unless(plan.pass_sorted, sorted_vals,
+                                  jnp.float32),
+                    _zeros_unless(plan.pass_sorted, sorted_idx, jnp.int32),
+                    bin_of,
+                    _zeros_unless(plan.pass_edges or not hist, bin_edges,
+                                  jnp.float32),
+                    ord_idx, leaf_of, w, stats,
+                    jnp.asarray(splittable_p), jnp.asarray(totals_np),
+                    jnp.asarray(row_counts_np), *maps_dev, fkey,
+                    jnp.int32(depth), plan=plan, Lp=Lp,
+                    need_partition=use_ord and depth + 1 < params.max_depth,
+                    subtract=subtract)
+            if carries:
+                tables = new_tables
+            _start_fetch((struct, next_totals))
+        with obs.span("repro.level.fetch", depth=depth, batch=batch):
+            host, totals_np = jax.device_get((struct, next_totals))
+        obs.count("level.fetch_bytes", _nbytes((host, totals_np)))
 
-        # Alg. 2 step 8: the host bookkeeping — grow the flat tree
-        next_open, any_split = _grow_level(acc, open_nodes, host, L, m_num,
-                                           depth, edges_np=edges_np)
+        with obs.span("repro.level.book", depth=depth, batch=batch):
+            if use_ord or carries:
+                row_counts_np = host["key_counts"]
+            if carries:
+                maps_src = (host["will_split"], host["key_counts"], L)
 
-        if collect_stats:
-            open_w = float(counts[1:L + 1].sum())
-            tbl_w = (Lp // 2 + 1) if subtract else (Lp + 1)
-            stats_log.append(LevelStats(
-                depth=depth, open_leaves=L,
-                network_bits_bitmap=int(open_w),
-                network_bits_supersplit=int(m * (Lp + 1) * 64),
-                class_list_bits=class_list.storage_bits(n, L),
-                feature_passes=int(min(m_prime * (1 if params.usb else L), m)),
-                rows_scanned=n * min(m_prime * (1 if params.usb else L), m),
-                hist_table_bytes=(m_num * tbl_w * params.num_bins
-                                  * int(stats.shape[-1]) * 4 if hist
-                                  else 0)))
+            # Alg. 2 step 8: the host bookkeeping — grow the flat tree
+            next_open, any_split = _grow_level(acc, open_nodes, host, L,
+                                               m_num, depth,
+                                               edges_np=edges_np)
+
+            if collect_stats:
+                open_w = float(counts[1:L + 1].sum())
+                tbl_w = (Lp // 2 + 1) if subtract else (Lp + 1)
+                passes = int(min(m_prime * (1 if params.usb else L), m))
+                stats_log.append(LevelStats(
+                    depth=depth, open_leaves=L,
+                    network_bits_bitmap=int(open_w),
+                    network_bits_supersplit=int(m * (Lp + 1) * 64),
+                    class_list_bits=class_list.storage_bits(n, L),
+                    feature_passes=passes, rows_scanned=n * passes,
+                    hist_table_bytes=(m_num * tbl_w * params.num_bins
+                                      * int(stats.shape[-1]) * 4 if hist
+                                      else 0)))
 
         if not any_split:
             break
@@ -614,18 +637,22 @@ def build_tree(
             drop = pruning.plan_drop(n, closed, plan.row_shards,
                                      params.prune_closed_frac)
             if drop and order_current:
-                (n, leaf_of, ord_idx, sorted_vals, sorted_idx, bin_of, num,
-                 cat, stats, w, labels) = pruning.compact_rows(
-                    keep=pruning.keep_mask(leaf_of == 0, drop), drop=drop,
-                    leaf_of=leaf_of, ord_idx=ord_idx,
-                    sorted_vals=sorted_vals, sorted_idx=sorted_idx,
-                    bin_of=bin_of, num=num, cat=cat, stats=stats, w=w,
-                    labels=labels, use_ord=use_ord, hist=hist, m_num=m_num)
+                with obs.span("repro.level.prep", depth=depth,
+                              batch=batch):
+                    (n, leaf_of, ord_idx, sorted_vals, sorted_idx, bin_of,
+                     num, cat, stats, w, labels) = pruning.compact_rows(
+                        keep=pruning.keep_mask(leaf_of == 0, drop),
+                        drop=drop, leaf_of=leaf_of, ord_idx=ord_idx,
+                        sorted_vals=sorted_vals, sorted_idx=sorted_idx,
+                        bin_of=bin_of, num=num, cat=cat, stats=stats, w=w,
+                        labels=labels, use_ord=use_ord, hist=hist,
+                        m_num=m_num)
                 if use_ord or carries:
                     row_counts_np = row_counts_np.copy()
                     row_counts_np[0] -= drop   # dropped rows were leaf 0
 
-    return _assemble_tree(acc, max_arity, m_num, task), stats_log
+    with obs.span("repro.forest.assemble", batch=batch):
+        return _assemble_tree(acc, max_arity, m_num, task), stats_log
 
 
 # ---------------------------------------------------------------------------
@@ -780,149 +807,164 @@ def build_forest(
     maps_src = None                       # (ws, key_counts, Ls) of level-1
     no_tables = jnp.zeros((T, 0, 0, 0, 0), jnp.float32)
     no_map = jnp.zeros((T, 0), jnp.int32)
+    batch = f"{tidx[0]}:{tidx[-1] + 1}"     # the trees, as a span arg
     for depth in range(params.max_depth + 1):
         if max(Ls) == 0:
             break
         Lp = _pad_leaves(max(Ls), params.leaf_pad)  # batch-max frontier
 
-        # carry the leaf totals into the new padding (root: compute once)
-        if totals_np is None:
-            totals_np = np.asarray(jax.vmap(
-                lambda lf, st, ww: _leaf_totals(lf, st, ww, Lp))(
-                    leaf_of, stats, w))
-            row_counts_np = np.zeros((T, Lp + 1), np.int32)
-            row_counts_np[:, 1] = n
-        else:
-            cur = np.zeros((T, Lp + 1, totals_np.shape[-1]), np.float32)
-            k = min(Lp + 1, totals_np.shape[1])   # rows past a tree's own
-            cur[:, :k] = totals_np[:, :k]         # frontier are all zero
-            totals_np = cur
-            cur_rc = np.zeros((T, Lp + 1), np.int32)
-            k = min(Lp + 1, row_counts_np.shape[1])
-            cur_rc[:, :k] = row_counts_np[:, :k]
-            row_counts_np = cur_rc
-        counts = cnt_np(totals_np)                # (T, Lp+1)
+        with obs.span("repro.level.prep", depth=depth, batch=batch):
+            # carry the leaf totals into the new padding (root: once)
+            if totals_np is None:
+                with obs.span("repro.level.fetch", depth=depth, batch=batch):
+                    # waits for the presort / quantize programs too
+                    totals_np = np.asarray(jax.vmap(
+                        lambda lf, st, ww: _leaf_totals(lf, st, ww, Lp))(
+                            leaf_of, stats, w))
+                row_counts_np = np.zeros((T, Lp + 1), np.int32)
+                row_counts_np[:, 1] = n
+            else:
+                cur = np.zeros((T, Lp + 1, totals_np.shape[-1]), np.float32)
+                k = min(Lp + 1, totals_np.shape[1])  # rows past a tree's
+                cur[:, :k] = totals_np[:, :k]        # frontier are zero
+                totals_np = cur
+                cur_rc = np.zeros((T, Lp + 1), np.int32)
+                k = min(Lp + 1, row_counts_np.shape[1])
+                cur_rc[:, :k] = row_counts_np[:, :k]
+                row_counts_np = cur_rc
+            counts = cnt_np(totals_np)                # (T, Lp+1)
 
-        # the splittable frontier mask (per-tree node VALUES are written by
-        # the deferred bookkeeping — they are not needed for dispatch)
-        at_max_depth = depth >= params.max_depth
-        splittable_p = np.zeros((T, Lp + 1), bool)
-        participate = [False] * T
-        if not at_max_depth:
-            for t in range(T):
-                if Ls[t] == 0:
-                    continue
-                sp = counts[t, 1:Ls[t] + 1] >= 2 * params.min_records
-                if sp.any():
-                    splittable_p[t, 1:Ls[t] + 1] = sp
-                    participate[t] = True
-        if not splittable_p.any():
-            # nothing to dispatch: drain the pipeline, write the final
-            # frontier's node values, stop
-            if pending is not None:
-                pending()
-                pending = None
-            write_values(Ls, counts, totals_np)
-            Ls = [0] * T
-            break
+            # the splittable frontier mask (per-tree node VALUES are
+            # written by the deferred bookkeeping — not needed to dispatch)
+            at_max_depth = depth >= params.max_depth
+            splittable_p = np.zeros((T, Lp + 1), bool)
+            participate = [False] * T
+            if not at_max_depth:
+                for t in range(T):
+                    if Ls[t] == 0:
+                        continue
+                    sp = counts[t, 1:Ls[t] + 1] >= 2 * params.min_records
+                    if sp.any():
+                        splittable_p[t, 1:Ls[t] + 1] = sp
+                        participate[t] = True
+            if not splittable_p.any():
+                # nothing to dispatch: drain the pipeline, write the final
+                # frontier's node values, stop
+                with obs.span("repro.level.book", depth=depth, batch=batch):
+                    if pending is not None:
+                        pending()
+                        pending = None
+                    write_values(Ls, counts, totals_np)
+                Ls = [0] * T
+                break
 
-        # Sprint pruning (paper §3), batched: drop rows closed in EVERY
-        # tree once they dominate (core/pruning.py).  Runs between levels
-        # (before dispatch), so the ord layout is always current here — the
-        # only level whose partition is skipped is the last one before
-        # max_depth, and that iteration breaks above instead of reaching
-        # this point.  The common-closed count rode home in the previous
-        # level's struct (`closed_rows`), so the trigger costs no extra
-        # dispatch or host sync and the pipelining stays intact.
-        if params.prune_closed_frac < 1.0 and n > 0:
-            drop = pruning.plan_drop(n, closed_np, plan.row_shards,
-                                     params.prune_closed_frac)
-            if drop:
-                keep_open = (leaf_of > 0).any(axis=0)      # (n,) device
-                (n, leaf_of, ord_idx, sorted_vals, sorted_idx, bin_of, num,
-                 cat, stats, w, labels) = pruning.compact_rows(
-                    keep=pruning.keep_mask(~keep_open, drop), drop=drop,
-                    leaf_of=leaf_of, ord_idx=ord_idx,
-                    sorted_vals=sorted_vals, sorted_idx=sorted_idx,
-                    bin_of=bin_of, num=num, cat=cat, stats=stats, w=w,
-                    labels=labels, use_ord=use_ord, hist=hist, m_num=m_num)
-                if use_ord or carries:
-                    row_counts_np = row_counts_np.copy()
-                    row_counts_np[:, 0] -= drop  # dropped rows were leaf 0
-                closed_np -= drop
+            # Sprint pruning (paper §3), batched: drop rows closed in EVERY
+            # tree once they dominate (core/pruning.py).  Runs between
+            # levels (before dispatch), so the ord layout is always current
+            # here — the only level whose partition is skipped is the last
+            # one before max_depth, and that iteration breaks above instead
+            # of reaching this point.  The common-closed count rode home in
+            # the previous level's struct (`closed_rows`), so the trigger
+            # costs no extra dispatch or host sync and the pipelining stays
+            # intact.
+            if params.prune_closed_frac < 1.0 and n > 0:
+                drop = pruning.plan_drop(n, closed_np, plan.row_shards,
+                                         params.prune_closed_frac)
+                if drop:
+                    keep_open = (leaf_of > 0).any(axis=0)      # (n,) device
+                    (n, leaf_of, ord_idx, sorted_vals, sorted_idx, bin_of,
+                     num, cat, stats, w, labels) = pruning.compact_rows(
+                        keep=pruning.keep_mask(~keep_open, drop), drop=drop,
+                        leaf_of=leaf_of, ord_idx=ord_idx,
+                        sorted_vals=sorted_vals, sorted_idx=sorted_idx,
+                        bin_of=bin_of, num=num, cat=cat, stats=stats, w=w,
+                        labels=labels, use_ord=use_ord, hist=hist,
+                        m_num=m_num)
+                    if use_ord or carries:
+                        row_counts_np = row_counts_np.copy()
+                        row_counts_np[:, 0] -= drop  # dropped rows: leaf 0
+                    closed_np -= drop
 
-        # histogram subtraction: per-tree maps from the previous level's
-        # split bitmap + child row counts (smaller child = build slot)
-        subtract = bool(carries and tables is not None
-                        and maps_src is not None)
-        if subtract:
-            ws_prev, kc_prev, Ls_prev = maps_src
-            parent_b = np.zeros((T, Lp + 1), np.int32)
-            sib_b = np.zeros((T, Lp + 1), np.int32)
-            slot_b = np.zeros((T, Lp + 1), np.int32)
-            for t in range(T):
-                if Ls_prev[t]:
-                    parent_b[t], sib_b[t], slot_b[t] = _child_maps(
-                        ws_prev[t], kc_prev[t], Ls_prev[t], Lp)
-            maps_dev = (tables, jnp.asarray(parent_b), jnp.asarray(sib_b),
-                        jnp.asarray(slot_b))
-        else:
-            maps_dev = (no_tables, no_map, no_map, no_map)
+            # histogram subtraction: per-tree maps from the previous
+            # level's split bitmap + child row counts (smaller child =
+            # build slot)
+            subtract = bool(carries and tables is not None
+                            and maps_src is not None)
+            if subtract:
+                ws_prev, kc_prev, Ls_prev = maps_src
+                parent_b = np.zeros((T, Lp + 1), np.int32)
+                sib_b = np.zeros((T, Lp + 1), np.int32)
+                slot_b = np.zeros((T, Lp + 1), np.int32)
+                for t in range(T):
+                    if Ls_prev[t]:
+                        parent_b[t], sib_b[t], slot_b[t] = _child_maps(
+                            ws_prev[t], kc_prev[t], Ls_prev[t], Lp)
+                maps_dev = (tables, jnp.asarray(parent_b),
+                            jnp.asarray(sib_b), jnp.asarray(slot_b))
+            else:
+                maps_dev = (no_tables, no_map, no_map, no_map)
 
         # the whole level of the whole batch on device: ONE dispatch,
         # one stacked struct back
-        _BATCH_STEP_CALLS[0] += 1
-        struct, leaf_of, ord_idx, next_totals, new_tables = \
-            _fused_level_step_batched(
-                _zeros_unless(plan.pass_num or not hist, num, jnp.float32),
-                cat, labels,
-                _zeros_unless(plan.pass_sorted, sorted_vals, jnp.float32),
-                _zeros_unless(plan.pass_sorted, sorted_idx, jnp.int32),
-                bin_of,
-                _zeros_unless(plan.pass_edges or not hist, bin_edges,
-                              jnp.float32),
-                ord_idx, leaf_of, w, stats,
-                jnp.asarray(splittable_p), jnp.asarray(totals_np),
-                jnp.asarray(row_counts_np), *maps_dev, fkeys,
-                jnp.int32(depth), plan=plan, Lp=Lp,
-                need_partition=use_ord and depth + 1 < params.max_depth,
-                subtract=subtract)
-        if carries:
-            tables = new_tables
-
-        # pipeline: start the D2H transfer, run the PREVIOUS level's host
-        # bookkeeping while the device executes this level, then block
-        for leaf in jax.tree_util.tree_leaves((struct, next_totals)):
-            if hasattr(leaf, "copy_to_host_async"):
-                leaf.copy_to_host_async()
+        obs.count("level.dispatches")
+        obs.count("level.tree_rows", sum(participate) * n)
+        with obs.span("repro.level.dispatch", depth=depth, batch=batch):
+            struct, leaf_of, ord_idx, next_totals, new_tables = \
+                _fused_level_step_batched(
+                    _zeros_unless(plan.pass_num or not hist, num,
+                                  jnp.float32),
+                    cat, labels,
+                    _zeros_unless(plan.pass_sorted, sorted_vals,
+                                  jnp.float32),
+                    _zeros_unless(plan.pass_sorted, sorted_idx, jnp.int32),
+                    bin_of,
+                    _zeros_unless(plan.pass_edges or not hist, bin_edges,
+                                  jnp.float32),
+                    ord_idx, leaf_of, w, stats,
+                    jnp.asarray(splittable_p), jnp.asarray(totals_np),
+                    jnp.asarray(row_counts_np), *maps_dev, fkeys,
+                    jnp.int32(depth), plan=plan, Lp=Lp,
+                    need_partition=use_ord and depth + 1 < params.max_depth,
+                    subtract=subtract)
+            if carries:
+                tables = new_tables
+            # pipeline: start the D2H transfer, run the PREVIOUS level's
+            # host bookkeeping while the device executes this level, then
+            # block
+            _start_fetch((struct, next_totals))
         if pending is not None:
-            pending()
+            with obs.span("repro.level.book", depth=depth, batch=batch):
+                pending()
             pending = None
 
         totals_cur = totals_np            # this level's totals, for values
-        host, totals_np = jax.device_get((struct, next_totals))
-        if use_ord or carries:
-            row_counts_np = host["key_counts"]
-        if carries:
-            maps_src = (host["will_split"], host["key_counts"], list(Ls))
-        closed_np = int(host["closed_rows"])
+        with obs.span("repro.level.fetch", depth=depth, batch=batch):
+            host, totals_np = jax.device_get((struct, next_totals))
+        obs.count("level.fetch_bytes", _nbytes((host, totals_np)))
 
         # next frontier sizes need only the split bitmap — the rest of the
         # bookkeeping is deferred to overlap the next dispatch
-        ws = host["will_split"]
-        Ls_next = [0] * T
-        for t in range(T):
-            if participate[t]:
-                Ls_next[t] = 2 * int(ws[t, 1:Ls[t] + 1].sum())
-        pending = make_book(depth, list(Ls), counts, totals_cur, host,
-                            list(participate), n)
-        Ls = Ls_next
+        with obs.span("repro.level.book", depth=depth, batch=batch):
+            if use_ord or carries:
+                row_counts_np = host["key_counts"]
+            if carries:
+                maps_src = (host["will_split"], host["key_counts"], list(Ls))
+            closed_np = int(host["closed_rows"])
+            ws = host["will_split"]
+            Ls_next = [0] * T
+            for t in range(T):
+                if participate[t]:
+                    Ls_next[t] = 2 * int(ws[t, 1:Ls[t] + 1].sum())
+            pending = make_book(depth, list(Ls), counts, totals_cur, host,
+                                list(participate), n)
+            Ls = Ls_next
 
     if pending is not None:               # safety drain (loop always breaks
         pending()                         # via the no-dispatch path above)
 
-    return ([_assemble_tree(a, max_arity, m_num, task) for a in accs],
-            stats_logs)
+    with obs.span("repro.forest.assemble", batch=batch):
+        return ([_assemble_tree(a, max_arity, m_num, task) for a in accs],
+                stats_logs)
 
 
 # ---------------------------------------------------------------------------
@@ -984,8 +1026,7 @@ def build_forest_streamed(
     from repro.core import checkpoint as checkpoint_lib
     from repro.core import dataset as dataset_lib
     from repro.core.dataset import RowSource
-    from repro.core.level.plan import (_STREAM_CHUNK_CALLS,
-                                       _stream_chunk_step,
+    from repro.core.level.plan import (_stream_chunk_step,
                                        _stream_finalize_step,
                                        _stream_score_step)
     if not isinstance(source, RowSource):
@@ -1126,102 +1167,113 @@ def build_forest_streamed(
         for lo in range(0, n_act, C_buf):
             hi = min(lo + C_buf, n_act)
             c = hi - lo
-            if c < C_buf:           # zero the pad of the final chunk
-                bins_buf[:, c:] = 0
-                labels_buf[c:] = 0
-                w_buf[:, c:] = 0.0
-                leaf_buf[:, c:] = 0
             try:
-                bins_buf[:, :c] = dataset_lib.read_with_retry(
-                    *((source.bins_block, lo, hi) if active is None
-                      else (source.bins_take, active[lo:hi])), **retry_kw)
+                with obs.span("repro.stream.read", depth=depth, row=lo):
+                    block = dataset_lib.read_with_retry(
+                        *((source.bins_block, lo, hi) if active is None
+                          else (source.bins_take, active[lo:hi])),
+                        **retry_kw)
             except dataset_lib.StreamReadError:
                 if ck is not None:  # persist the last completed level so
                     ck.flush()      # the resume loses only this one
                 raise
-            labels_buf[:c] = labels_np[lo:hi]
-            w_buf[:, :c] = w_np[:, lo:hi]
-            leaf_buf[:, :c] = leaf_np[:, lo:hi]
-            _STREAM_CHUNK_CALLS[0] += 1
-            leaf_c, acc_dev = _stream_chunk_step(
-                bins_buf, labels_buf, w_buf, leaf_buf, *dec, acc_dev,
-                plan=plan, Lp=Lp, Lpp=Lpp, root=root,
-                need_tables=need_tables)
-            leaf_np[:, lo:hi] = np.asarray(leaf_c)[:, :c]
+            with obs.span("repro.stream.stage", depth=depth, row=lo):
+                if c < C_buf:       # zero the pad of the final chunk
+                    bins_buf[:, c:] = 0
+                    labels_buf[c:] = 0
+                    w_buf[:, c:] = 0.0
+                    leaf_buf[:, c:] = 0
+                bins_buf[:, :c] = block
+                labels_buf[:c] = labels_np[lo:hi]
+                w_buf[:, :c] = w_np[:, lo:hi]
+                leaf_buf[:, :c] = leaf_np[:, lo:hi]
+            obs.count("stream.chunk_dispatches")
+            with obs.span("repro.stream.dispatch", depth=depth, row=lo):
+                leaf_c, acc_dev = _stream_chunk_step(
+                    bins_buf, labels_buf, w_buf, leaf_buf, *dec, acc_dev,
+                    plan=plan, Lp=Lp, Lpp=Lpp, root=root,
+                    need_tables=need_tables)
+            with obs.span("repro.stream.fetch", depth=depth, row=lo):
+                leaf_np[:, lo:hi] = np.asarray(leaf_c)[:, :c]
 
-        # --- finalize: merged tables + per-leaf totals -------------------
-        if need_tables:
-            merged, totals_dev = _stream_finalize_step(acc_dev, plan=plan)
-            totals_np = np.asarray(totals_dev)
-        else:
-            merged, totals_np = None, np.asarray(acc_dev)
-        counts = totals_np.sum(-1)                        # classification
+        with obs.span("repro.stream.score", depth=depth):
+            # --- finalize: merged tables + per-leaf totals ---------------
+            if need_tables:
+                merged, totals_dev = _stream_finalize_step(acc_dev,
+                                                           plan=plan)
+                totals_np = np.asarray(totals_dev)
+            else:
+                merged, totals_np = None, np.asarray(acc_dev)
+            counts = totals_np.sum(-1)                    # classification
 
-        for t in range(T):
-            for h in range(1, Ls[t] + 1):
-                accs[t].set_value(open_nodes[t][h - 1], totals_np[t, h],
-                                  counts[t, h], task)
-
-        splittable_p = np.zeros((T, Lp + 1), bool)
-        if not at_max_depth:
             for t in range(T):
-                if Ls[t]:
-                    splittable_p[t, 1:Ls[t] + 1] = \
-                        counts[t, 1:Ls[t] + 1] >= 2 * params.min_records
-        if not splittable_p.any():
-            break                         # values already written
+                for h in range(1, Ls[t] + 1):
+                    accs[t].set_value(open_nodes[t][h - 1], totals_np[t, h],
+                                      counts[t, h], task)
 
-        # --- score: one program on the tables alone ----------------------
-        res = _stream_score_step(merged, jnp.asarray(splittable_p), fkeys,
-                                 jnp.int32(depth), plan=plan, Lp=Lp)
-        host = jax.device_get({k: res[k] for k in
-                               ("best_feat", "best_gain", "thr",
-                                "will_split")})
-        dec = (res["feat_of_leaf"], res["thr"], res["new_left"],
-               res["new_right"])
-        Lpp = Lp
+            splittable_p = np.zeros((T, Lp + 1), bool)
+            if not at_max_depth:
+                for t in range(T):
+                    if Ls[t]:
+                        splittable_p[t, 1:Ls[t] + 1] = \
+                            counts[t, 1:Ls[t] + 1] >= 2 * params.min_records
+            if not splittable_p.any():
+                break                     # values already written
 
-        ws = host["will_split"]
-        no_mask = np.zeros((Lp + 1, 1), bool)             # numeric-only
-        Ls_next = [0] * T
-        for t in range(T):
-            if Ls[t] == 0:
-                continue
-            host_t = {k: host[k][t] for k in
-                      ("best_feat", "best_gain", "thr", "will_split")}
-            host_t["mask"] = no_mask
-            next_open, any_split = _grow_level(
-                accs[t], open_nodes[t], host_t, Ls[t], m_num, depth,
-                edges_np=edges_np)
-            if collect_stats:
-                Lp_t = _pad_leaves(Ls[t], params.leaf_pad)
-                passes = int(min(m_prime * (1 if params.usb else Ls[t]),
-                                 m_num))
-                stats_logs[t].append(LevelStats(
-                    depth=depth, open_leaves=Ls[t],
-                    network_bits_bitmap=int(counts[t, 1:Ls[t] + 1].sum()),
-                    network_bits_supersplit=int(m_num * (Lp_t + 1) * 64),
-                    class_list_bits=class_list.storage_bits(n_act, Ls[t]),
-                    feature_passes=passes, rows_scanned=n_act * passes,
-                    hist_table_bytes=m_num * (Lp_t + 1) * params.num_bins
-                    * S_dim * 4))
-            if any_split:
-                open_nodes[t] = next_open
-            Ls_next[t] = 2 * int(ws[t, 1:Ls[t] + 1].sum())
-        Ls = Ls_next
+            # --- score: one program on the tables alone ------------------
+            res = _stream_score_step(merged, jnp.asarray(splittable_p),
+                                     fkeys, jnp.int32(depth), plan=plan,
+                                     Lp=Lp)
+            host = jax.device_get({k: res[k] for k in
+                                   ("best_feat", "best_gain", "thr",
+                                    "will_split")})
+            dec = (res["feat_of_leaf"], res["thr"], res["new_left"],
+                   res["new_right"])
+            Lpp = Lp
 
-        # --- Sprint pruning, HOST-side: drop rows closed in every tree ---
-        # (result-invariant; fixed-shape padded chunks need no divisibility)
-        if params.prune_closed_frac < 1.0 and n_act > 0 and max(Ls) > 0:
-            open_any = (leaf_np > 0).any(axis=0)
-            closed = n_act - int(open_any.sum())
-            if closed > 0 and closed / n_act >= params.prune_closed_frac:
-                keep = np.flatnonzero(open_any)
-                active = keep if active is None else active[keep]
-                leaf_np = np.ascontiguousarray(leaf_np[:, keep])
-                w_np = np.ascontiguousarray(w_np[:, keep])
-                labels_np = np.ascontiguousarray(labels_np[keep])
-                n_act = len(keep)
+        with obs.span("repro.level.book", depth=depth):
+            ws = host["will_split"]
+            no_mask = np.zeros((Lp + 1, 1), bool)             # numeric-only
+            Ls_next = [0] * T
+            for t in range(T):
+                if Ls[t] == 0:
+                    continue
+                host_t = {k: host[k][t] for k in
+                          ("best_feat", "best_gain", "thr", "will_split")}
+                host_t["mask"] = no_mask
+                next_open, any_split = _grow_level(
+                    accs[t], open_nodes[t], host_t, Ls[t], m_num, depth,
+                    edges_np=edges_np)
+                if collect_stats:
+                    Lp_t = _pad_leaves(Ls[t], params.leaf_pad)
+                    passes = int(min(m_prime * (1 if params.usb else Ls[t]),
+                                     m_num))
+                    stats_logs[t].append(LevelStats(
+                        depth=depth, open_leaves=Ls[t],
+                        network_bits_bitmap=int(counts[t, 1:Ls[t] + 1].sum()),
+                        network_bits_supersplit=int(m_num * (Lp_t + 1) * 64),
+                        class_list_bits=class_list.storage_bits(n_act, Ls[t]),
+                        feature_passes=passes, rows_scanned=n_act * passes,
+                        hist_table_bytes=m_num * (Lp_t + 1) * params.num_bins
+                        * S_dim * 4))
+                if any_split:
+                    open_nodes[t] = next_open
+                Ls_next[t] = 2 * int(ws[t, 1:Ls[t] + 1].sum())
+            Ls = Ls_next
+
+            # --- Sprint pruning, HOST-side: drop rows closed in every
+            # tree (result-invariant; fixed-shape padded chunks need no
+            # divisibility)
+            if params.prune_closed_frac < 1.0 and n_act > 0 and max(Ls) > 0:
+                open_any = (leaf_np > 0).any(axis=0)
+                closed = n_act - int(open_any.sum())
+                if closed > 0 and closed / n_act >= params.prune_closed_frac:
+                    keep = np.flatnonzero(open_any)
+                    active = keep if active is None else active[keep]
+                    leaf_np = np.ascontiguousarray(leaf_np[:, keep])
+                    w_np = np.ascontiguousarray(w_np[:, keep])
+                    labels_np = np.ascontiguousarray(labels_np[keep])
+                    n_act = len(keep)
 
         # end-of-level state, post-bookkeeping.  The final level's snapshot
         # is never written: finish_batch commits the trees immediately
@@ -1234,7 +1286,8 @@ def build_forest_streamed(
                 active=active, dec=dec, Lpp=Lpp, accs=accs,
                 open_nodes=open_nodes, stats_logs=stats_logs))
 
-    trees = [_assemble_tree(a, 1, m_num, task) for a in accs]
+    with obs.span("repro.forest.assemble"):
+        trees = [_assemble_tree(a, 1, m_num, task) for a in accs]
     if ck is not None:
         ck.finish_batch(tidx, trees, stats_logs)
     return trees, stats_logs

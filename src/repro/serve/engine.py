@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import transformer
 from repro.train import sharding as shd
 
@@ -146,16 +147,22 @@ class ForestServer:
                             f"0..{a - 1})")
         return cat.astype(np.int32, copy=False)
 
-    def predict(self, num, cat=None):
-        """(B, C) forest-mean distributions; ONE jitted call.
+    def predict(self, num, cat=None) -> np.ndarray:
+        """(B, C) forest-mean distributions on the host; ONE jitted call.
 
         Malformed requests raise `InvalidRequest` before the descent —
         the caller answers the client and keeps serving (no state to
-        recover; see tests/test_server_robust.py)."""
-        num = np.asarray(num, np.float32)
-        cat = self._validate(num, cat)
-        return self.packed.predict_proba(jnp.asarray(num),
-                                         jnp.asarray(cat, jnp.int32))
+        recover; see tests/test_server_robust.py).  Its phases are the
+        spans `repro.serve.{validate,transfer,descent,fetch}`."""
+        with obs.span("repro.serve.validate"):
+            num = np.asarray(num, np.float32)
+            cat = self._validate(num, cat)
+        with obs.span("repro.serve.transfer"):
+            num_d, cat_d = jnp.asarray(num), jnp.asarray(cat, jnp.int32)
+        with obs.span("repro.serve.descent"):
+            out = self.packed.predict_proba(num_d, cat_d)
+        with obs.span("repro.serve.fetch"):
+            return np.asarray(out)
 
 
 def prefill_step(params, inputs, cfg, unroll: bool = False):

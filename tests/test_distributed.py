@@ -126,6 +126,7 @@ def test_sharded_batched_forest_exact_and_hist():
     (one per depth) — not T·D — level programs for the whole batch."""
     print(_run("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro import obs
         from repro.core import distributed, tree as tree_lib
         from repro.core.dataset import from_numpy
         from repro.core.forest import RandomForest
@@ -145,14 +146,14 @@ def test_sharded_batched_forest_exact_and_hist():
         ]
         for p, eng in configs:
             local = RandomForest(p, num_trees=4, seed=11, tree_batch=4).fit(ds)
-            c0 = tree_lib._BATCH_STEP_CALLS[0]
-            s0 = tree_lib._STEP_CALLS[0]
+            c0 = obs.counter("level.dispatches")
+            s0 = obs.counter("level.tree_dispatches")
             dist = RandomForest(p, num_trees=4, seed=11,
                                 tree_batch=4).fit(ds, engine=eng)
             D = max(t.max_depth_reached for t in dist.trees)
-            programs = tree_lib._BATCH_STEP_CALLS[0] - c0
+            programs = obs.counter("level.dispatches") - c0
             assert D <= programs <= p.max_depth + 1, (programs, D)
-            assert tree_lib._STEP_CALLS[0] == s0      # no per-tree fallback
+            assert obs.counter("level.tree_dispatches") == s0  # no per-tree
             for ta, tb in zip(local.trees, dist.trees):
                 assert ta.num_nodes == tb.num_nodes
                 np.testing.assert_array_equal(ta.feature, tb.feature)

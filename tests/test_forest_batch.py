@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import bagging, presort, tree as tree_lib
 from repro.core.dataset import from_numpy
 from repro.core.forest import RandomForest
@@ -142,17 +143,17 @@ def test_one_level_program_per_depth_trace_counted(mixed_ds):
     rf = RandomForest(p, num_trees=16, seed=0, tree_batch=16)
     rf.fit(mixed_ds)                                   # warm the jit caches
 
-    calls0 = tree_lib._BATCH_STEP_CALLS[0]
-    steps0 = tree_lib._STEP_CALLS[0]
-    traces0 = tree_lib._BATCH_STEP_TRACES[0]
+    calls0 = obs.counter("level.dispatches")
+    steps0 = obs.counter("level.tree_dispatches")
+    traces0 = obs.counter("level.traces")
     rf2 = RandomForest(p, num_trees=16, seed=0, tree_batch=16).fit(mixed_ds)
-    calls = tree_lib._BATCH_STEP_CALLS[0] - calls0
+    calls = obs.counter("level.dispatches") - calls0
     D = max(t.max_depth_reached for t in rf2.trees)
     # one dispatch per depth level actually run, for the whole 16-tree batch
     assert D <= calls <= p.max_depth + 1, (calls, D)
     # no per-tree fused dispatches, no retraces on the warm cache
-    assert tree_lib._STEP_CALLS[0] == steps0
-    assert tree_lib._BATCH_STEP_TRACES[0] == traces0
+    assert obs.counter("level.tree_dispatches") == steps0
+    assert obs.counter("level.traces") == traces0
     for ta, tb in zip(rf.trees, rf2.trees):
         _assert_identical(ta, tb, "warm-vs-cold")
 
@@ -215,14 +216,14 @@ def test_batched_pruning_stays_batched_and_exact():
     base = RandomForest(tree_lib.TreeParams(max_depth=8, min_records=50),
                         num_trees=3, seed=3, tree_batch=3).fit(ds)
     for backend in ("segment", "scan"):
-        calls0 = tree_lib._BATCH_STEP_CALLS[0]
-        steps0 = tree_lib._STEP_CALLS[0]
+        calls0 = obs.counter("level.dispatches")
+        steps0 = obs.counter("level.tree_dispatches")
         pruned = RandomForest(
             tree_lib.TreeParams(max_depth=8, min_records=50, backend=backend,
                                 prune_closed_frac=0.3),
             num_trees=3, seed=3, tree_batch=3).fit(ds)
-        assert tree_lib._BATCH_STEP_CALLS[0] > calls0, backend
-        assert tree_lib._STEP_CALLS[0] == steps0, backend
+        assert obs.counter("level.dispatches") > calls0, backend
+        assert obs.counter("level.tree_dispatches") == steps0, backend
         for ta, tb in zip(base.trees, pruned.trees):
             _assert_identical(ta, tb, f"batched-pruned/{backend}")
 
@@ -245,13 +246,14 @@ def test_legacy_supersplit_fn_warns_and_uses_per_tree_builder(mixed_ds):
 
     p = tree_lib.TreeParams(max_depth=3)
     plain = RandomForest(p, num_trees=2, seed=4).fit(mixed_ds)
-    calls0 = tree_lib._BATCH_STEP_CALLS[0]
-    steps0 = tree_lib._STEP_CALLS[0]
+    calls0 = obs.counter("level.dispatches")
+    steps0 = obs.counter("level.tree_dispatches")
     with pytest.warns(UserWarning, match="per-tree builder"):
         legacy = RandomForest(p, num_trees=2, seed=4).fit(
             mixed_ds, supersplit_fn=legacy_fn)
-    assert tree_lib._BATCH_STEP_CALLS[0] == calls0   # no batched programs
-    assert tree_lib._STEP_CALLS[0] > steps0          # per-tree dispatches
+    # no batched programs, per-tree dispatches
+    assert obs.counter("level.dispatches") == calls0
+    assert obs.counter("level.tree_dispatches") > steps0
     for ta, tb in zip(plain.trees, legacy.trees):
         _assert_identical(ta, tb, "legacy-vs-plain")
 

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import forest as forest_lib
 from repro.core import presort, splits, tree as tree_lib
 from repro.core.dataset import from_numpy
@@ -175,13 +176,13 @@ def test_predict_proba_single_jitted_call_100_trees(mixed_ds):
         saved = tree_lib._predict_jit
         tree_lib._predict_jit = boom
         try:
-            traces0 = forest_lib._PREDICT_TRACES[0]
+            traces0 = obs.counter("predict.traces")
             p1 = rf.predict_proba(mixed_ds.num, mixed_ds.cat)
             assert len(calls) == 1                    # exactly one jitted call
-            assert forest_lib._PREDICT_TRACES[0] - traces0 <= 1  # one trace
+            assert obs.counter("predict.traces") - traces0 <= 1  # one trace
             p2 = rf.predict_proba(mixed_ds.num, mixed_ds.cat)
             assert len(calls) == 2
-            assert forest_lib._PREDICT_TRACES[0] - traces0 <= 1  # no retrace
+            assert obs.counter("predict.traces") - traces0 <= 1  # no retrace
         finally:
             tree_lib._predict_jit = saved
     finally:

@@ -5,8 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import forest as forest_lib
-from repro.core import gbt as gbt_lib
+from repro import obs
 from repro.core import tree as tree_lib
 from repro.core.dataset import from_numpy
 from repro.core.gbt import GBTModel, GBTParams
@@ -44,13 +43,13 @@ def test_predict_raw_single_call_no_tree_loop(reg_ds):
     saved = tree_lib._predict_jit
     tree_lib._predict_jit = boom
     try:
-        traces0 = gbt_lib._RAW_TRACES[0]
-        ptraces0 = forest_lib._PREDICT_TRACES[0]
+        traces0 = obs.counter("gbt.raw_traces")
+        ptraces0 = obs.counter("predict.traces")
         f1 = gbt.predict_raw(reg_ds.num, reg_ds.cat)
-        assert gbt_lib._RAW_TRACES[0] - traces0 <= 1       # one trace
+        assert obs.counter("gbt.raw_traces") - traces0 <= 1       # one trace
         f2 = gbt.predict_raw(reg_ds.num, reg_ds.cat)
-        assert gbt_lib._RAW_TRACES[0] - traces0 <= 1       # no retrace
-        assert forest_lib._PREDICT_TRACES[0] - ptraces0 <= 1
+        assert obs.counter("gbt.raw_traces") - traces0 <= 1       # no retrace
+        assert obs.counter("predict.traces") - ptraces0 <= 1
     finally:
         tree_lib._predict_jit = saved
 

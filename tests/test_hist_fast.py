@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import presort, splits, tree as tree_lib
 from repro.core.dataset import from_numpy
 from repro.core.forest import RandomForest
@@ -193,15 +194,15 @@ def test_fast_path_one_level_program_per_depth(skewed_ds):
     falls back to per-tree dispatches; warm refits do not retrace."""
     p = tree_lib.TreeParams(max_depth=5, split_mode="hist", num_bins=16)
     rf = RandomForest(p, num_trees=4, seed=0, tree_batch=4).fit(skewed_ds)
-    calls0 = tree_lib._BATCH_STEP_CALLS[0]
-    steps0 = tree_lib._STEP_CALLS[0]
-    traces0 = tree_lib._BATCH_STEP_TRACES[0]
+    calls0 = obs.counter("level.dispatches")
+    steps0 = obs.counter("level.tree_dispatches")
+    traces0 = obs.counter("level.traces")
     rf2 = RandomForest(p, num_trees=4, seed=0, tree_batch=4).fit(skewed_ds)
-    calls = tree_lib._BATCH_STEP_CALLS[0] - calls0
+    calls = obs.counter("level.dispatches") - calls0
     D = max(t.max_depth_reached for t in rf2.trees)
     assert D <= calls <= p.max_depth + 1, (calls, D)
-    assert tree_lib._STEP_CALLS[0] == steps0
-    assert tree_lib._BATCH_STEP_TRACES[0] == traces0
+    assert obs.counter("level.tree_dispatches") == steps0
+    assert obs.counter("level.traces") == traces0
     for ta, tb in zip(rf.trees, rf2.trees):
         _assert_identical(ta, tb, "warm-vs-cold")
 

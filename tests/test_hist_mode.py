@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import presort, splits, tree as tree_lib
 from repro.core.dataset import from_numpy
 from repro.core.forest import RandomForest
@@ -183,15 +184,16 @@ def test_hist_one_level_program_per_depth(mixed_ds):
     rf = RandomForest(p, num_trees=8, seed=0, tree_batch=8)
     rf.fit(mixed_ds)                                   # warm the jit caches
 
-    calls0 = tree_lib._BATCH_STEP_CALLS[0]
-    steps0 = tree_lib._STEP_CALLS[0]
-    traces0 = tree_lib._BATCH_STEP_TRACES[0]
+    calls0 = obs.counter("level.dispatches")
+    steps0 = obs.counter("level.tree_dispatches")
+    traces0 = obs.counter("level.traces")
     rf2 = RandomForest(p, num_trees=8, seed=0, tree_batch=8).fit(mixed_ds)
-    calls = tree_lib._BATCH_STEP_CALLS[0] - calls0
+    calls = obs.counter("level.dispatches") - calls0
     D = max(t.max_depth_reached for t in rf2.trees)
     assert D <= calls <= p.max_depth + 1, (calls, D)
-    assert tree_lib._STEP_CALLS[0] == steps0           # no per-tree fallback
-    assert tree_lib._BATCH_STEP_TRACES[0] == traces0   # warm: no retrace
+    # no per-tree fallback; warm: no retrace
+    assert obs.counter("level.tree_dispatches") == steps0
+    assert obs.counter("level.traces") == traces0
     for ta, tb in zip(rf.trees, rf2.trees):
         _assert_identical(ta, tb, "hist-warm-vs-cold")
 

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import presort, splits, tree as tree_lib
 from repro.core.dataset import (ArrayRowSource, MemmapRowSource, RowSource,
                                 from_numpy)
@@ -203,17 +204,16 @@ def test_streaming_one_program_per_level_shape(hist_setup):
     """Chunk-program compilations are bounded by the number of distinct
     (level shape) configurations — O(log L), never O(chunks) — and a warm
     refit with identical shapes adds chunk CALLS but ZERO new traces."""
-    from repro.core.level import plan as plan_mod
     ds, params, _ = hist_setup
     src = ArrayRowSource.from_dataset(ds, params.num_bins, chunk_size=123)
 
-    c0 = plan_mod._STREAM_CHUNK_CALLS[0]
-    t0 = plan_mod._STREAM_CHUNK_TRACES[0]
-    s0 = plan_mod._STREAM_SCORE_TRACES[0]
+    c0 = obs.counter("stream.chunk_dispatches")
+    t0 = obs.counter("stream.traces")
+    s0 = obs.counter("stream.score_traces")
     RandomForest(params=params, num_trees=3, seed=7).fit_streamed(src)
-    calls = plan_mod._STREAM_CHUNK_CALLS[0] - c0
-    traces = plan_mod._STREAM_CHUNK_TRACES[0] - t0
-    straces = plan_mod._STREAM_SCORE_TRACES[0] - s0
+    calls = obs.counter("stream.chunk_dispatches") - c0
+    traces = obs.counter("stream.traces") - t0
+    straces = obs.counter("stream.score_traces") - s0
     chunks_per_level = -(-900 // 123)
     assert calls >= chunks_per_level          # it really streamed
     # statics are (plan, Lp, Lpp, root, need_tables): at most one trace per
@@ -224,13 +224,13 @@ def test_streaming_one_program_per_level_shape(hist_setup):
     assert straces <= params.max_depth + 1
 
     # warm refit: same shapes -> zero new compilations, calls still grow
-    t1 = plan_mod._STREAM_CHUNK_TRACES[0]
-    s1 = plan_mod._STREAM_SCORE_TRACES[0]
-    c1 = plan_mod._STREAM_CHUNK_CALLS[0]
+    t1 = obs.counter("stream.traces")
+    s1 = obs.counter("stream.score_traces")
+    c1 = obs.counter("stream.chunk_dispatches")
     RandomForest(params=params, num_trees=3, seed=7).fit_streamed(src)
-    assert plan_mod._STREAM_CHUNK_TRACES[0] == t1
-    assert plan_mod._STREAM_SCORE_TRACES[0] == s1
-    assert plan_mod._STREAM_CHUNK_CALLS[0] > c1
+    assert obs.counter("stream.traces") == t1
+    assert obs.counter("stream.score_traces") == s1
+    assert obs.counter("stream.chunk_dispatches") > c1
 
 
 # ---------------------------------------------------------------------------
