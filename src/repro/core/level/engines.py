@@ -119,6 +119,12 @@ class SplitEngine:
         """Row-shard count the driver must keep n divisible by (pruning)."""
         return 1
 
+    def table_updates(self, st: LevelStatics, n: int, subtract: bool) -> int:
+        """Scatter updates one tree's table build makes at a level of n
+        rows (the `level.table_updates` counter); 0 for an engine that
+        scatters no bin tables."""
+        return 0
+
     # -- out-of-core streaming (DESIGN.md §8) -------------------------------
     #
     # A streaming-capable hist engine splits its table build into a
@@ -263,6 +269,19 @@ def _hist_build_rows(inp, subtract, compact):
             inp.stats[idxc], inp.labels[idxc])
 
 
+def _class_ids(labels, st):
+    """The class ids `feature_count_tables` scatters at (one update per
+    row and column), or None for regression's plane path."""
+    return labels if st.task == "classification" else None
+
+
+def _scatter_updates(st, rows):
+    """Updates one tree's `feature_count_tables` call makes over `rows`
+    rows: one per column on the class path, S per column on the plane
+    path (regression, S = 3)."""
+    return st.m_num * rows * (1 if st.task == "classification" else 3)
+
+
 def _expand_subtracted(packed, prev_tables, parent_of, sib_of, slot_of):
     """Full-width tables from packed build tables + the parent recurrence.
 
@@ -309,8 +328,9 @@ class HistNumeric(SplitEngine):
                                                  ww, stt, labels))(
                 leaf, w, stats)
         # the tree axis folds into the one flat scatter (no vmap)
-        return acc + splits.feature_count_tables(bins, leaf, w, stats, Lp,
-                                                 st.num_bins)
+        return acc + splits.feature_count_tables(
+            bins, leaf, w, stats, Lp, st.num_bins,
+            labels=_class_ids(labels, st))
 
     def stream_finalize(self, acc):
         return acc
@@ -322,7 +342,13 @@ class HistNumeric(SplitEngine):
                 bins, slots, w, labels, B=st.num_bins, W=W, task=st.task,
                 num_classes=st.num_classes)
         return splits.feature_count_tables(bins, slots, w, stats, W - 1,
-                                           st.num_bins)
+                                           st.num_bins,
+                                           labels=_class_ids(labels, st))
+
+    def table_updates(self, st, n, subtract):
+        if self.backend == "kernel":
+            return 0
+        return _scatter_updates(st, max(n // 2, 1) if subtract else n)
 
     def supersplits(self, inp, st, Lp, cand):
         Wb = Lp // 2 + 1 if st.subtract else Lp + 1

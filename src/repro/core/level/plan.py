@@ -143,6 +143,12 @@ class LevelPlan:
         return self.use_bin_cuts and self.numeric.carries_tables \
             and self.hist_subtract and self.task == "classification"
 
+    def table_updates(self, n: int, subtract: bool) -> int:
+        """Scatter updates one tree's bin-table build makes at a level of
+        n rows (`SplitEngine.table_updates`; 0 without numeric columns)."""
+        return (self.numeric.table_updates(self.statics, n, subtract)
+                if self.m_num else 0)
+
     @property
     def row_shards(self) -> int:
         """Row-shard count n must stay divisible by (device pruning).

@@ -37,7 +37,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import splits
-from repro.core.level.engines import SplitEngine, _expand_subtracted
+from repro.core.level.engines import (SplitEngine, _class_ids,
+                                      _expand_subtracted, _scatter_updates)
 
 def _shmap(f, mesh, in_specs, out_specs):
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
@@ -209,20 +210,24 @@ class ShardedHistNumeric(_MeshEngine):
 
     def stream_accumulate(self, acc, bins, leaf, w, stats, labels, st, Lp):
         B = st.num_bins
+        ids = _class_ids(labels, st)
         if self.row_axis is None:
             return acc + splits.feature_count_tables(
-                bins, leaf, w, stats, Lp, B)[None]
+                bins, leaf, w, stats, Lp, B, labels=ids)[None]
 
-        def local(a, bo, lf, ww, stt):
-            # a (1, T, m_loc, L+1, S, B); bo (m_loc, c_loc); lf/ww (T, c_loc)
-            return a + splits.feature_count_tables(bo, lf, ww, stt, Lp,
-                                                   B)[None]
+        def local(a, bo, lf, ww, stt, *lb):
+            # a (1, T, m_loc, L+1, S, B); bo (m_loc, c_loc); lf/ww (T, c_loc);
+            # lb = (labels (c_loc,),) on the class path
+            return a + splits.feature_count_tables(
+                bo, lf, ww, stt, Lp, B, labels=lb[0] if lb else None)[None]
 
         F, R = self.feature_axis, self.row_axis
-        return _shmap(local, self.mesh,
-                      in_specs=(self._acc_spec(), P(F, R), P(None, R),
-                                P(None, R), P(None, R, None)),
-                      out_specs=self._acc_spec())(acc, bins, leaf, w, stats)
+        in_specs = (self._acc_spec(), P(F, R), P(None, R), P(None, R),
+                    P(None, R, None)) + ((P(R),) if ids is not None else ())
+        args = (acc, bins, leaf, w, stats) + ((ids,) if ids is not None
+                                              else ())
+        return _shmap(local, self.mesh, in_specs=in_specs,
+                      out_specs=self._acc_spec())(*args)
 
     def stream_finalize(self, acc):
         if self.row_axis is None:
@@ -235,18 +240,22 @@ class ShardedHistNumeric(_MeshEngine):
                       out_specs=P(None, self.feature_axis, None, None,
                                   None))(acc)
 
+    def table_updates(self, st, n, subtract):
+        # no compaction: every row scatters, derive rows to slot 0
+        return _scatter_updates(st, n)
+
     def supersplits(self, inp, st, Lp, cand):
         one = lambda x: None if x is None else x[None]
         res = self._search(inp.bin_of, one(inp.leaf_of), one(inp.w),
                            one(inp.stats), one(cand), Lp, st,
                            one(inp.prev_tables), one(inp.parent_of),
-                           one(inp.sib_of), one(inp.slot_of))
+                           one(inp.sib_of), one(inp.slot_of), inp.labels)
         return tuple(r[0] for r in res)
 
     def supersplits_batched(self, inp, st, Lp, cand):
         return self._search(inp.bin_of, inp.leaf_of, inp.w, inp.stats,
                             cand, Lp, st, inp.prev_tables, inp.parent_of,
-                            inp.sib_of, inp.slot_of)
+                            inp.sib_of, inp.slot_of, inp.labels)
 
     def __call__(self, bin_of, bin_edges, leaf_of, w, stats, cand, Lp,
                  impurity, task, min_records):
@@ -257,26 +266,34 @@ class ShardedHistNumeric(_MeshEngine):
                           num_classes=stats.shape[-1],
                           num_bins=bin_edges.shape[-1], impurity=impurity,
                           task=task, min_records=min_records)
+        # one_hot(label) · w rows: the argmax is the class wherever w > 0
+        labels = (jnp.argmax(stats, axis=-1) if task == "classification"
+                  else None)
         g, c = self._search(bin_of, leaf_of[None], w[None], stats[None],
-                            cand[None], Lp, st, None, None, None, None)
+                            cand[None], Lp, st, None, None, None, None,
+                            labels)
         cuts = c[0].astype(jnp.int32)
         thr = jnp.take_along_axis(bin_edges, cuts, axis=1)
         return g[0], jnp.where(jnp.isfinite(g[0]), thr, 0.0)
 
     def _search(self, bin_of, leaf_of, w, stats, cand, Lp, st,
-                prev_tables, parent_of, sib_of, slot_of):
+                prev_tables, parent_of, sib_of, slot_of, labels):
         F, R = self.feature_axis, self.row_axis
         B = st.num_bins
         subtract = st.subtract
+        labels = _class_ids(labels, st)
         Wb = Lp // 2 + 1 if subtract else Lp + 1
         impurity, task, min_records = st.impurity, st.task, st.min_records
 
-        def local(bo, cl, lf, ww, stt, *sub):
+        def local(bo, cl, lf, ww, stt, *rest):
             # bo (m_loc, n_loc); cl (T, m_loc, L+1); lf/ww (T, n_loc);
-            # stt (T, n_loc, S); sub = (prev (T, m_loc, Wprev, S, B),
-            # parent/sib/slot (T, L+1)) when subtracting
+            # stt (T, n_loc, S); rest = ([labels (n_loc,)] on the class
+            # path, then prev (T, m_loc, Wprev, S, B), parent/sib/slot
+            # (T, L+1) when subtracting)
             # NO row compaction here: the build-rows <= n/2 bound is
             # global, not per row shard — derive rows mask to slot 0
+            lb, sub = ((rest[0], rest[1:]) if labels is not None
+                       else (None, rest))
             with jax.named_scope("level.supersplit.tables"):
                 if subtract:
                     prev, par, sib, slot = sub
@@ -284,7 +301,7 @@ class ShardedHistNumeric(_MeshEngine):
                 else:
                     ids = lf
                 packed = splits.feature_count_tables(bo, ids, ww, stt,
-                                                     Wb - 1, B)
+                                                     Wb - 1, B, labels=lb)
                 if R is not None:
                     # THE merge: one psum of the (T, m_loc, Wb, S, B)
                     # tables — under subtraction only build slots cross
@@ -312,6 +329,9 @@ class ShardedHistNumeric(_MeshEngine):
         in_specs = [P(F, R), P(None, F, None), P(None, R), P(None, R),
                     P(None, R, None)]
         args = [bin_of, cand, leaf_of, w, stats]
+        if labels is not None:
+            in_specs.append(P(R))
+            args.append(labels)
         if subtract:
             in_specs += [tab_spec, P(None, None), P(None, None),
                          P(None, None)]

@@ -409,6 +409,7 @@ def feature_count_tables(
     stats: jnp.ndarray,          # ([T,] n, S) row stats
     num_slots: int,              # table width minus one (slots 1..num_slots)
     num_bins: int,
+    labels: jnp.ndarray | None = None,   # ([T,] n) int class ids, or None
 ) -> jnp.ndarray:
     """([T,] m, num_slots+1, S, B) per-leaf bin tables for ALL m features
     in ONE scatter over the flat ([tree,] feature, slot, stat, bin) index
@@ -424,14 +425,24 @@ def feature_count_tables(
 
     `leaf_ids` are pre-mapped scatter SLOTS, not necessarily raw leaf ids:
     the subtraction path (level/engines.py) passes the packed build-leaf
-    slots with derive-leaf rows mapped to the discarded slot 0.  The
-    updates are one flat vector with rows minor, (T, m, S, n): see the
-    note on the stat axis above.
+    slots with derive-leaf rows mapped to the discarded slot 0.
 
-    A leading tree axis T on `leaf_ids`/`w`/`stats` (the bins are shared)
-    folds into the same flat index space.  A vmap would give a scatter
-    with a batch dimension, whose compile time for a v5e grows with n:
-    91 s against 24 s flat for T = 4 at n = 2^18, and 414 s at 2^20.
+    Two update layouts, chosen by the task the caller knows statically:
+
+      * class path (`labels` given, classification): a row's stats are
+        `one_hot(label) · w`, so it makes ONE update per column — `w` at
+        its own class's stat — in place of S updates of which S − 1 add
+        0.0.  The updates are (T, m, n); the sums, and their order per
+        cell, are those of the plane path, so the tables are bit-equal.
+      * plane path (`labels` None, regression's S = 3 nonzero stats): one
+        update per (column, stat), (T, m, S, n) with rows minor (see the
+        note on the stat axis above).
+
+    A leading tree axis T on `leaf_ids`/`w`/`stats`/`labels` (the bins are
+    shared; `labels` may omit it) folds into the same flat index space.
+    A vmap would give a scatter with a batch dimension, whose compile time
+    for a v5e grows with n: 91 s against 24 s flat for T = 4 at n = 2^18,
+    and 414 s at 2^20.
     """
     batched = leaf_ids.ndim == 2
     if not batched:
@@ -440,22 +451,36 @@ def feature_count_tables(
     m = bin_of.shape[0]
     W = num_slots + 1
     S = stats.shape[-1]
+    if labels is not None:
+        labels = jnp.broadcast_to(labels, (T, n)).astype(jnp.int32)
     if T * m * W * S * num_bins >= 2 ** 31:   # int32 flat index overflow
-        return jax.vmap(lambda lf, ww, st: feature_count_tables(
-            bin_of, lf, ww, st, num_slots, num_bins))(leaf_ids, w, stats)
+        return jax.vmap(lambda lf, ww, st, *lb: feature_count_tables(
+            bin_of, lf, ww, st, num_slots, num_bins, *lb))(
+            leaf_ids, w, stats, *([] if labels is None else [labels]))
     inbag = (w > 0) & (leaf_ids > 0)                        # (T, n)
-    contrib = jnp.where(inbag[:, None], jnp.swapaxes(stats, 1, 2),
-                        0.0)                                # (T, S, n)
-    slot_stat = (leaf_ids.astype(jnp.int32)[:, None] * S
-                 + jnp.arange(S, dtype=jnp.int32)[None, :, None])
     tree_feat = (jnp.arange(T, dtype=jnp.int32)[:, None] * m
                  + jnp.arange(m, dtype=jnp.int32)[None, :])  # (T, m)
-    flat = ((tree_feat[:, :, None, None] * (W * S)
-             + slot_stat[:, None]) * num_bins
-            + bin_of.astype(jnp.int32)[None, :, None, :])   # (T, m, S, n)
+    bins = bin_of.astype(jnp.int32)[None]                   # (1, m, n)
+    if labels is not None:
+        # one_hot(label, S) is all zero off [0, S): so is the update
+        inbag = inbag & (labels >= 0) & (labels < S)
+        contrib = jnp.where(inbag, w, 0.0)                  # (T, n)
+        slot_stat = leaf_ids.astype(jnp.int32) * S + labels  # (T, n)
+        flat = ((tree_feat[:, :, None] * (W * S)
+                 + slot_stat[:, None]) * num_bins + bins)   # (T, m, n)
+        updates = jnp.broadcast_to(contrib[:, None], (T, m, n))
+    else:
+        contrib = jnp.where(inbag[:, None], jnp.swapaxes(stats, 1, 2),
+                            0.0)                            # (T, S, n)
+        slot_stat = (leaf_ids.astype(jnp.int32)[:, None] * S
+                     + jnp.arange(S, dtype=jnp.int32)[None, :, None])
+        flat = ((tree_feat[:, :, None, None] * (W * S)
+                 + slot_stat[:, None]) * num_bins
+                + bins[:, :, None, :])                      # (T, m, S, n)
+        updates = jnp.broadcast_to(contrib[:, None], (T, m, S, n))
     table = jax.ops.segment_sum(
-        jnp.broadcast_to(contrib[:, None], (T, m, S, n)).reshape(-1),
-        flat.reshape(-1), num_segments=T * m * W * S * num_bins)
+        updates.reshape(-1), flat.reshape(-1),
+        num_segments=T * m * W * S * num_bins)
     table = table.reshape(T, m, W, S, num_bins)
     return table if batched else table[0]
 

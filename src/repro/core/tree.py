@@ -568,6 +568,7 @@ def build_tree(
         # the whole level on device: one dispatch, one small struct back
         obs.count("level.tree_dispatches")
         obs.count("level.tree_rows", n)
+        obs.count("level.table_updates", plan.table_updates(n, subtract))
         with obs.span("repro.level.dispatch", depth=depth, batch=batch):
             struct, leaf_of, ord_idx, next_totals, new_tables = \
                 _fused_level_step(
@@ -908,6 +909,8 @@ def build_forest(
         # one stacked struct back
         obs.count("level.dispatches")
         obs.count("level.tree_rows", sum(participate) * n)
+        obs.count("level.table_updates",
+                  sum(participate) * plan.table_updates(n, subtract))
         with obs.span("repro.level.dispatch", depth=depth, batch=batch):
             struct, leaf_of, ord_idx, next_totals, new_tables = \
                 _fused_level_step_batched(
@@ -1188,6 +1191,9 @@ def build_forest_streamed(
                 w_buf[:, :c] = w_np[:, lo:hi]
                 leaf_buf[:, :c] = leaf_np[:, lo:hi]
             obs.count("stream.chunk_dispatches")
+            if need_tables:
+                obs.count("level.table_updates",
+                          T * plan.table_updates(C_buf, False))
             with obs.span("repro.stream.dispatch", depth=depth, row=lo):
                 leaf_c, acc_dev = _stream_chunk_step(
                     bins_buf, labels_buf, w_buf, leaf_buf, *dec, acc_dev,

@@ -25,6 +25,9 @@ The registry holds, among others:
   level.tree_dispatches   per-tree level programs dispatched (build_tree)
   level.traces            level programs traced (compilations)
   level.tree_rows         sum over dispatches of trees x rows in the state
+  level.table_updates     sum over dispatches of the bin-table scatter's
+                          updates: per tree, columns x rows scattered
+                          (x S on regression's plane path)
   level.fetch_bytes       bytes of level structs and totals fetched
   stream.chunk_dispatches streamed chunk programs dispatched
   stream.traces           streamed chunk programs traced

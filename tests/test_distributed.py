@@ -11,9 +11,9 @@ import pytest
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
-def _run(code: str) -> str:
+def _run(code: str, devices: int = 8) -> str:
     env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=SRC)
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env, timeout=900)
@@ -161,6 +161,46 @@ def test_sharded_batched_forest_exact_and_hist():
                 np.testing.assert_array_equal(ta.value, tb.value)
         print('SHARDED-BATCHED-OK')
     """))
+
+
+def test_sharded_hist_class_path_equals_one_device():
+    """The sharded hist engine on a 2x2 mesh of forced CPU devices
+    scatters at the rows' class ids in every table build (in-memory fit
+    with subtraction, and streamed chunks) and trains the one-device
+    trees node for node."""
+    print(_run("""
+        import numpy as np
+        from repro.core import splits, tree as tree_lib
+        from repro.core.dataset import ArrayRowSource
+        from repro.core.forest import RandomForest
+        from repro.core.level.sharded import ShardedHistNumeric
+        from repro.data.synthetic import make_tabular
+        from repro.launch.mesh import make_host_mesh
+        seen = []
+        build = splits.feature_count_tables
+        def spy(*args, labels=None, **kw):
+            seen.append(labels is not None)
+            return build(*args, labels=labels, **kw)
+        ds = make_tabular('xor', n=640, num_informative=4, num_useless=4,
+                          seed=2)
+        p = tree_lib.TreeParams(max_depth=5, split_mode='hist', num_bins=16)
+        local = RandomForest(p, num_trees=2, seed=3).fit(ds)
+        splits.feature_count_tables = spy
+        eng = ShardedHistNumeric(mesh=make_host_mesh(2, 2))
+        dist = RandomForest(p, num_trees=2, seed=3).fit(ds, engine=eng)
+        in_memory = len(seen)
+        src = ArrayRowSource.from_dataset(ds, p.num_bins, chunk_size=200)
+        streamed = RandomForest(p, num_trees=2, seed=3).fit_streamed(
+            src, engine=eng)
+        assert in_memory and len(seen) > in_memory and all(seen), seen
+        def fingerprint(rf):
+            return [(t.num_nodes, t.feature.tolist(), t.threshold.tolist(),
+                     t.children.tolist(), t.value.tolist(),
+                     t.n_node.tolist()) for t in rf.trees]
+        assert fingerprint(dist) == fingerprint(local)
+        assert fingerprint(streamed) == fingerprint(local)
+        print('SHARDED-HIST-CLASS-PATH-OK')
+    """, devices=4))
 
 
 @pytest.mark.slow

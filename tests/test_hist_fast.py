@@ -13,10 +13,15 @@ Contracts under test (ISSUE 5):
   * the fast path keeps one batched level program per depth (dispatch-
     and trace-counted), and regression (GBT) forces the plain rebuild;
   * pre-quantized bucket state that disagrees with TreeParams raises at
-    fit time instead of being silently ignored.
+    fit time instead of being silently ignored;
+  * classification tables scatter ONE update per (row, column), at the
+    row's class, bit-equal to the S-plane scatter, and the level program
+    and the `level.table_updates` counter show that path.
 """
 import dataclasses
+import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -147,6 +152,172 @@ def test_feature_tables_discard_slot_rows_do_not_leak():
     np.testing.assert_array_equal(np.asarray(part)[:, 3:],
                                   np.asarray(full)[:, 3:])
     assert np.asarray(part)[:, 2].sum() == 0
+
+
+# ---------------------------------------------------------------------------
+# Class path: one update per (row, column) at the row's class
+# ---------------------------------------------------------------------------
+
+def _table_case(seed, T, n, m, L, B, discards):
+    rng = np.random.default_rng(seed)
+    bins = rng.integers(0, B, size=(m, n)).astype(np.uint8)
+    slots = rng.integers(0 if discards else 1, L + 1,
+                         size=(T, n)).astype(np.int32)
+    w = rng.poisson(1.0, size=(T, n)).astype(np.float32)  # w = 0 rows too
+    assert (w == 0).any() and (slots == 0).any() == discards
+    return rng, bins, slots, w
+
+
+def _tables(bins, slots, w, stats, L, B, T, **kw):
+    """T = 1 takes the unbatched form (no tree axis)."""
+    if T == 1:
+        slots, w, stats = slots[0], w[0], stats[0]
+        kw = {k: v[0] if v.ndim == 2 else v for k, v in kw.items()}
+    return np.asarray(splits.feature_count_tables(
+        jnp.asarray(bins), jnp.asarray(slots), jnp.asarray(w), stats, L, B,
+        **kw))
+
+
+@pytest.mark.parametrize("discards", [False, True])
+@pytest.mark.parametrize("T", [1, 2])
+@pytest.mark.parametrize("S", [2, 7])
+def test_class_path_tables_bit_equal_plane_path(S, T, discards):
+    """`labels=y` (w at the row's class, one update per row and column)
+    gives the plane scatter's tables bit for bit: Poisson bag weights,
+    w = 0 rows, slot-0 discards, class ids off [0, S), class ids shared
+    by the trees or given per tree."""
+    n, m, L, B = 700, 5, 6, 255
+    rng, bins, slots, w = _table_case(S * 10 + T, T, n, m, L, B, discards)
+    y = rng.integers(0, S, n).astype(np.int32)
+    y[:3] = (-1, S, S + 4)        # off [0, S): one_hot adds nothing
+    w[:, :3] = 2.0
+    stats = jnp.stack([splits.row_stats(jnp.asarray(y), jnp.asarray(wt), S,
+                                        "classification") for wt in w])
+    plane = _tables(bins, slots, w, stats, L, B, T)
+    assert plane.shape == (T,) * (T > 1) + (m, L + 1, S, B)
+    assert plane.sum() > 0
+    for labels in (y, np.broadcast_to(y, (T, n))):
+        klass = _tables(bins, slots, w, stats, L, B, T,
+                        labels=jnp.asarray(labels))
+        np.testing.assert_array_equal(klass.view(np.uint32),
+                                      plane.view(np.uint32))
+
+
+@pytest.mark.parametrize("T", [1, 2])
+def test_regression_plane_path_unchanged(T):
+    """`labels=None` keeps the plane scatter: each tree's [w, wy, wy²]
+    tables equal the per-column `categorical_count_table` bit for bit."""
+    n, m, L, B = 600, 4, 5, 64
+    rng, bins, slots, w = _table_case(40 + T, T, n, m, L, B, True)
+    y = rng.normal(size=n).astype(np.float32)
+    stats = jnp.stack([splits.row_stats(jnp.asarray(y), jnp.asarray(wt), 3,
+                                        "regression") for wt in w])
+    got = _tables(bins, slots, w, stats, L, B, T).reshape(T, m, L + 1, 3, B)
+    for t in range(T):
+        want = np.stack([np.asarray(splits.categorical_count_table(
+            jnp.asarray(bins[j].astype(np.int32)), jnp.asarray(slots[t]),
+            jnp.asarray(w[t]), stats[t], L, B)) for j in range(m)])
+        np.testing.assert_array_equal(got[t].view(np.uint32),
+                                      want.view(np.uint32))
+
+
+def _f32_scatter_add_sizes(jaxpr):
+    """Updates' element count of every f32 scatter-add in a jaxpr and in
+    the jaxprs nested in it (jit, lax.map, cond)."""
+    sizes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scatter-add":
+            upd = eqn.invars[2].aval
+            if upd.dtype == jnp.float32:
+                sizes.append(upd.size)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    sizes += _f32_scatter_add_sizes(sub)
+    return sizes
+
+
+@pytest.mark.parametrize("task", ["classification", "regression"])
+def test_level_step_scatters_one_update_per_row_and_column(monkeypatch,
+                                                           task):
+    """The traced hist level program's table scatter has m·rows updates
+    per tree for classification (rows = n at the root, n//2 once
+    subtraction compacts the build rows), never m·S·rows; regression's
+    plane scatter keeps m·3·rows."""
+    n, m, C = 520, 5, 7
+    rng = np.random.default_rng(6)
+    num = rng.normal(size=(n, m)).astype(np.float32)
+    if task == "classification":
+        y = (np.digitize(num[:, 0], [-1, -.5, 0, .3, .6, 1])
+             + (num[:, 1] > 0)) % C
+        ds = from_numpy(num, None, y.astype(np.int32))
+        assert ds.num_classes == C
+        p = tree_lib.TreeParams(max_depth=3, split_mode="hist", num_bins=16)
+    else:
+        ds = from_numpy(num, None, (num[:, 0] + num[:, 1] ** 2)
+                        .astype(np.float32), task="regression")
+        p = tree_lib.TreeParams(max_depth=3, split_mode="hist", num_bins=16,
+                                task="regression", impurity="variance")
+    # trees in sequence inside the program, as at the benchmark's sizes
+    monkeypatch.setattr(tree_lib, "_BATCH_VMAP_ELEMS", 0)
+    calls = []
+    step = tree_lib._fused_level_step_batched
+
+    def spy(*args, **kw):
+        calls.append((args, kw))
+        return step(*args, **kw)
+    monkeypatch.setattr(tree_lib, "_fused_level_step_batched", spy)
+    RandomForest(p, num_trees=2, seed=1).fit(ds)
+    assert len(calls) >= 2
+    subtracted = 0
+    for args, kw in calls:
+        sizes = _f32_scatter_add_sizes(
+            jax.make_jaxpr(functools.partial(step, **kw))(*args).jaxpr)
+        if task == "classification":
+            rows = n // 2 if kw["subtract"] else n
+            subtracted += kw["subtract"]
+            assert m * rows in sizes, (kw, sizes)
+            assert m * C * rows not in sizes, (kw, sizes)
+        else:
+            assert not kw["subtract"]
+            assert m * 3 * n in sizes, (kw, sizes)
+    assert subtracted or task == "regression"
+
+
+@pytest.mark.parametrize("task,mode,subtract", [
+    ("classification", "hist", False),
+    ("classification", "hist", True),
+    ("regression", "hist", False),
+    ("classification", "exact", False),
+])
+def test_table_updates_counter(skewed_ds, task, mode, subtract):
+    """`level.table_updates` per tree-level is m·rows for classification
+    (n//2 rows under subtraction past the root), m·S·rows for regression
+    (S = 3) and 0 where no bin table is scattered."""
+    n, m = skewed_ds.n, skewed_ds.m_num
+    ds = skewed_ds
+    kw = dict(max_depth=4, split_mode=mode, num_bins=16,
+              hist_subtract=subtract)
+    if task == "regression":
+        ds = from_numpy(np.asarray(ds.num), None,
+                        np.asarray(ds.num)[:, 0] * 2.0, task="regression")
+        kw.update(task="regression", impurity="variance")
+    T = 2
+    before = obs.counters()
+    RandomForest(tree_lib.TreeParams(**kw), num_trees=T, seed=3,
+                 tree_batch=T).fit(ds)
+    d = obs.delta(before)
+    tree_rows, updates = d["level.tree_rows"], d.get("level.table_updates")
+    assert tree_rows % n == 0 and tree_rows > T * n    # past the root
+    if mode == "exact":
+        assert updates == 0
+    elif task == "regression":
+        assert updates == m * 3 * tree_rows
+    elif not subtract:
+        assert updates == m * tree_rows
+    else:
+        assert updates == m * (T * n + (tree_rows // n - T) * (n // 2))
 
 
 # ---------------------------------------------------------------------------

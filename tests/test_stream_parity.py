@@ -110,6 +110,30 @@ def test_streamed_fit_with_pruning():
     _assert_forests_identical(ref, fs, "pruned")
 
 
+def test_class_path_engages_in_fit_and_fit_streamed(monkeypatch):
+    """Every table build of a classification fit, in memory and streamed,
+    scatters at the rows' class ids (`labels=`), and the two fits still
+    give the same trees node for node.  The shapes are this test's own,
+    so the level and chunk programs trace afresh under the spy."""
+    seen = []
+    build = splits.feature_count_tables
+
+    def spy(*args, labels=None, **kw):
+        seen.append(labels is not None)
+        return build(*args, labels=labels, **kw)
+    monkeypatch.setattr(splits, "feature_count_tables", spy)
+    ds = make_tabular("xor", n=731, num_informative=4, num_useless=3,
+                      seed=8)
+    params = tree_lib.TreeParams(max_depth=5, split_mode="hist",
+                                 num_bins=24)
+    ref = RandomForest(params=params, num_trees=2, seed=5).fit(ds)
+    in_memory = len(seen)
+    src = ArrayRowSource.from_dataset(ds, params.num_bins, chunk_size=250)
+    fs = RandomForest(params=params, num_trees=2, seed=5).fit_streamed(src)
+    assert in_memory and len(seen) > in_memory and all(seen), seen
+    _assert_forests_identical(ref, fs, "class-path")
+
+
 def test_memmap_source_parity(hist_setup, tmp_path):
     """Disk-backed bin cache (built by the streaming quantizer, no full
     float column ever materialized) trains the same trees, and its edges
