@@ -39,9 +39,9 @@ def train_control(cell, seed, trees):
     traffic, cfg = cell["traffic"], cell["config"]
     tp = traffic["tree_params"]
     n = traffic.get("rows") or cfg["rows"]
-    X, y = cell["generate"](n, seed)
+    X, cat, y, arities = harness.rows(cell, n, seed)
     data = reference.Data(X, y, cfg["classes"], tp["split_mode"],
-                          tp.get("num_bins", 255))
+                          tp.get("num_bins", 255), cat=cat, arities=arities)
     gap, errors = 0.0, 0
     for t in range(trees):
         fs = harness.forest_seed(seed, 1)

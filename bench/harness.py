@@ -6,12 +6,24 @@ belongs to one configuration, traffic mix or per-layer metric sits in a
 file of its own, found by name:
 
   bench/configs/<config>.json   sizes, source, cuts and assumptions
-  bench/configs/<config>.py     `generate(n, seed) -> (X, y)`, the rows
+  bench/configs/<config>.py     `generate(n, seed)`, the rows
   bench/traffic/<traffic>.json  the mix: its `kind` ("train" or "serve",
                                 the module in bench/ that runs it), the
                                 job's parameters and the limits of the
                                 check that decides `correct`
   bench/metrics/<metric>.py     `read(run) -> float | None`
+
+`generate` returns `(X, y)`, numeric columns (n, m) float32 and labels,
+or `(num, cat, y)` for a configuration with categorical columns: `cat`
+(n, m_cat) int32 ids.  Such a configuration's JSON gives
+`categorical_columns` (m_cat) and `arities`, one per column: the
+source's published cardinality, not the largest id drawn; ids lie in
+[0, arity).  The library numbers numeric columns first, then the
+categorical ones, and so does `bench/reference.py`.
+
+A `train` traffic mix may give `mesh` {"data": d, "model": k} with d·k
+the cell's `chips`: its fits then run on that mesh with the library's
+sharded engines (`bench/train.py`).
 
 `bench/later.json`, of `BENCHMARK.json`'s form, holds cells kept for a
 later benchmark with their metrics: `load_cell` finds them too, so they
@@ -61,6 +73,15 @@ def load_cell(workload: str, root: Path = ROOT) -> dict:
             "generate": gen.generate,
             "end_to_end": [m for m in spec["end_to_end"] if applies(m)],
             "per_layer": [m for m in spec["per_layer"] if applies(m)]}
+
+
+def rows(cell: dict, n: int, seed: int):
+    """The configuration's rows from the seed: (X, cat, y, arities), with
+    cat None and arities () for a numeric-only configuration."""
+    out = cell["generate"](n, seed)
+    if len(out) == 2:
+        return out[0], None, out[1], ()
+    return out[0], out[1], out[2], tuple(cell["config"]["arities"])
 
 
 def peaks(device_kind: str) -> dict:

@@ -1,7 +1,9 @@
 """The level step's share of its roofline: the least time of the traced
-level programs (`bench/roofline.py` bytes over the chip's HBM bandwidth)
-over the device time of the programs named `*fused_level_step*` in the
-trace (`core/level/plan.py` `_fused_level_step[_batched]`)."""
+level programs (`bench/roofline.py` bytes over the HBM bandwidth of the
+run's chips together) over the device time of the programs named
+`*fused_level_step*` in the trace (`core/level/plan.py`
+`_fused_level_step[_batched]`), which `trace_reduce` averages over the
+chips.  On one chip the divisor is that chip's bandwidth."""
 
 NAME = "fused_level_step"
 
@@ -14,4 +16,5 @@ def read(run):
     secs = sum(v["seconds"] for k, v in s["modules"].items() if NAME in k)
     if secs <= 0:
         return None
-    return 100.0 * nbytes / run["peaks"]["hbm_bytes_per_s"] / secs
+    bandwidth = run["device"]["count"] * run["peaks"]["hbm_bytes_per_s"]
+    return 100.0 * nbytes / bandwidth / secs

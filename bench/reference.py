@@ -8,7 +8,17 @@ program under test:
   split modes: exact (every midpoint between consecutive distinct in-bag
   values of a candidate column) and hist (every cut between equi-depth
   quantile buckets, `x <= edges[b]`);
+* categorical columns in either mode (Breiman's ordering, paper §2.4):
+  at a node, the categories present among its in-bag rows are ordered by
+  P(last class | v), ties by category id, and every prefix of that order
+  is a candidate left set; a row goes left iff its category is in the
+  node's left set.  Categories are counted with `np.unique` over the
+  node's rows, never with an array of the column's arity, which may
+  reach 10^7;
 * forest descent (`x <= threshold` goes left, leaves average).
+
+Columns are numbered as the library numbers them: numeric columns
+0..m_num-1, then categorical columns m_num..m-1.
 
 The seeded draws are part of the specification (paper §2.2: every worker
 derives the same draw from (seed, tree)): per-row bag counts are
@@ -28,7 +38,11 @@ candidate columns.  It reports:
 * `node_errors`: nodes whose weight or class distribution differs from
   the routed rows, splits at nodes that may not split, splits on a
   column that is not a candidate or at a threshold that is no candidate
-  cut, and nodes the walk never reaches.
+  cut (a categorical left set that leaves a side under `min_records`),
+  left sets holding ids outside [0, arity), and nodes the walk never
+  reaches.
+
+A trained tree's categorical left sets are read through `cat_left`.
 
 `build_tree` grows the reference's own tree with gains computed in a
 given precision: float32 follows the program node for node; bfloat16 is
@@ -38,10 +52,13 @@ from __future__ import annotations
 
 import functools
 import math
+import os
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
 SPLIT_EPS = 1e-9        # a leaf splits only on a gain above this
+THREADS = min(8, os.cpu_count() or 1)   # columns searched side by side
 
 
 # ---------------------------------------------------------------------------
@@ -149,17 +166,28 @@ def _seg_first_max(vals, seg, num_segments):
 # ---------------------------------------------------------------------------
 
 class Data:
-    """Rows, labels and (hist) bucket ids, shared by the trees checked."""
+    """Rows, labels and (hist) bucket ids, shared by the trees checked.
 
-    def __init__(self, X, y, num_classes, mode, num_bins=255):
+    `cat` (n, m_cat) holds the categorical columns' ids and `arities`
+    their published cardinalities (ids lie in [0, arity))."""
+
+    def __init__(self, X, y, num_classes, mode, num_bins=255, cat=None,
+                 arities=()):
         self.X = np.ascontiguousarray(X, np.float32)
         self.y = np.asarray(y, np.int64)
-        self.n, self.m = self.X.shape
+        self.n, self.m_num = self.X.shape
+        self.cat = (np.zeros((self.n, 0), np.int64) if cat is None
+                    else np.asarray(cat, np.int64))
+        self.arities = np.asarray(arities, np.int64)
+        if self.arities.shape != (self.cat.shape[1],):
+            raise ValueError("one arity per categorical column")
+        self.m = self.m_num + self.cat.shape[1]
         self.C = num_classes
         self.mode = mode
         if mode == "hist":
-            self.edges = quantize_edges(self.X, num_bins)
-            self.bins = bin_columns(self.X, self.edges)
+            if self.m_num:
+                self.edges = quantize_edges(self.X, num_bins)
+                self.bins = bin_columns(self.X, self.edges)
         elif mode != "exact":
             raise ValueError(f"unknown split mode {mode!r}")
 
@@ -224,15 +252,79 @@ def _search_feature(data, f, rows, pos, w, totals, leaves, min_records,
     return best, thr_best, held
 
 
+def _search_categorical(data, f, rows, pos, w, totals, leaves,
+                        min_records, dtype, prog_feat, prog_left):
+    """Best left set of categorical column f for each leaf in `leaves`.
+
+    Returns (best gain, its left set (a sorted id array per leaf), gain
+    of the held split), the gains (len(leaves),); -inf where f gives no
+    valid cut or the held set is none.  Ordering metric and gains are in
+    float64 for the check and in float32 otherwise, as the library
+    computes them.
+    """
+    k = len(leaves)
+    j = f - data.m_num
+    local = np.full(totals.shape[0], -1)
+    local[leaves] = np.arange(k)
+    p = local[pos[rows]]
+    v = data.cat[rows, j]
+    # (leaf, category) groups in (leaf, id) order, class weights summed
+    key, inv = np.unique(p * data.arities[j] + v, return_inverse=True)
+    tab = np.bincount(inv.reshape(-1) * data.C + data.y[rows],
+                      weights=w[rows], minlength=len(key) * data.C
+                      ).reshape(len(key), data.C)
+    g_leaf, g_cat = key // data.arities[j], key % data.arities[j]
+    tot = totals[leaves]
+    mdt = np.float64 if dtype == np.float64 else np.float32
+    count = tab.sum(1)
+    metric = tab[:, -1].astype(mdt) / count.astype(mdt)
+    order = np.lexsort((g_cat, metric, g_leaf))
+    g_leaf, g_cat, tab = g_leaf[order], g_cat[order], tab[order]
+    U = len(key)
+    cum = np.cumsum(tab, axis=0)
+    start = np.r_[True, g_leaf[1:] != g_leaf[:-1]]
+    first = np.maximum.accumulate(np.where(start, np.arange(U), 0))
+    within = cum - (cum[first] - tab[first])    # prefix inside each leaf
+    cut = np.flatnonzero(g_leaf[:-1] == g_leaf[1:])   # cut after cut[i]
+    best = np.full(k, -np.inf)
+    lefts = {}
+    if cut.size:
+        seg = g_leaf[cut]
+        left = within[cut]
+        right = tot[seg] - left
+        ok = (left.sum(1) >= min_records) & (right.sum(1) >= min_records)
+        gain = np.where(ok, _gain(left, right, dtype), -np.inf)
+        best, at = _seg_first_max(gain, seg, k)
+        for h in np.flatnonzero(at >= 0):
+            c = cut[at[h]]
+            lefts[int(h)] = np.sort(g_cat[first[c]:c + 1])
+    held = np.full(k, -np.inf)
+    mine = np.flatnonzero(prog_feat[leaves] == f)
+    if mine.size:
+        ids = [np.asarray(prog_left[int(leaves[h])], np.int64) for h in mine]
+        held_keys = np.concatenate(
+            [h * data.arities[j] + i[(i >= 0) & (i < data.arities[j])]
+             for h, i in zip(mine, ids)])
+        goes = np.isin(g_leaf * data.arities[j] + g_cat, held_keys)
+        left = np.zeros((k, data.C))
+        np.add.at(left, g_leaf[goes], tab[goes])
+        left, right = left[mine], tot[mine] - left[mine]
+        ok = (left.sum(1) >= min_records) & (right.sum(1) >= min_records)
+        held[mine] = np.where(ok, _gain(left, right, dtype), -np.inf)
+    return best, lefts, held
+
+
 def search_level(data, pos, w, cand, totals, min_records, dtype,
-                 prog_feat=None, prog_thr=None):
+                 prog_feat=None, prog_thr=None, prog_left=None):
     """Best split of every leaf of one level over its candidate columns.
 
     pos (n,): leaf index of each row, -1 outside the level's open leaves;
     cand (L, m) bool; totals (L, C) in-bag class weights.  The first
     column and, within it, the first cut win ties.  Returns (gain,
-    feature, threshold, gain of the held split (feature prog_feat[h] at
-    threshold prog_thr[h]; -inf where it is no valid candidate cut)).
+    feature, threshold, left sets {leaf: sorted ids} of the leaves whose
+    best column is categorical, gain of the held split (feature
+    prog_feat[h] at threshold prog_thr[h] or left set prog_left[h]; -inf
+    where it is no valid candidate cut)).
     """
     L = totals.shape[0]
     if prog_feat is None:
@@ -241,21 +333,44 @@ def search_level(data, pos, w, cand, totals, min_records, dtype,
     best = np.full(L, -np.inf)
     feat = np.full(L, -1)
     thr = np.zeros(L, np.float32)
+    lefts = {}
     held = np.full(L, -np.inf)
     inbag = (w > 0) & (pos >= 0)
-    for f in range(data.m):
+
+    def column(f):
         leaves = np.flatnonzero(cand[:, f])
         if leaves.size == 0:
-            continue
+            return None
         rows = np.flatnonzero(inbag & cand[np.maximum(pos, 0), f])
-        g, t, h = _search_feature(data, f, rows, pos, w, totals, leaves,
-                                  min_records, dtype, prog_feat, prog_thr)
+        if f < data.m_num:
+            g, t, h = _search_feature(data, f, rows, pos, w, totals, leaves,
+                                      min_records, dtype, prog_feat,
+                                      prog_thr)
+            return leaves, g, t, {}, h
+        g, sets, h = _search_categorical(data, f, rows, pos, w, totals,
+                                         leaves, min_records, dtype,
+                                         prog_feat, prog_left)
+        return leaves, g, np.zeros(len(leaves), np.float32), sets, h
+
+    # columns are searched side by side (numpy's sorts and scans release
+    # the GIL) and merged in column order, so the result is the same
+    with ThreadPoolExecutor(THREADS) as pool:
+        found = list(pool.map(column, range(data.m)))
+    for f, res in enumerate(found):
+        if res is None:
+            continue
+        leaves, g, t, sets, h = res
         better = g > best[leaves]
         best[leaves] = np.where(better, g, best[leaves])
         feat[leaves] = np.where(better, f, feat[leaves])
         thr[leaves] = np.where(better, t, thr[leaves])
+        for i in np.flatnonzero(better):
+            if f < data.m_num:
+                lefts.pop(int(leaves[i]), None)
+            else:
+                lefts[int(leaves[i])] = sets[int(i)]
         held[leaves] = np.maximum(held[leaves], h)
-    return best, feat, thr, held
+    return best, feat, thr, lefts, held
 
 
 # ---------------------------------------------------------------------------
@@ -274,24 +389,62 @@ def _level_state(data, node_of, nodes, w):
     return pos, totals
 
 
-def tree_arrays(tree) -> dict:
+def cat_left(tree, node: int) -> np.ndarray:
+    """Sorted int32 ids of the categories a trained tree's categorical
+    node sends left: `tree.cat_left(node)` where the tree has that
+    accessor, else the ids set in its dense `(N, max_arity)` mask."""
+    if isinstance(tree, dict):
+        return np.asarray(tree["cat_left"][int(node)], np.int32)
+    if hasattr(tree, "cat_left"):
+        return np.asarray(tree.cat_left(int(node)), np.int32)
+    return np.flatnonzero(tree.cat_mask[int(node)]).astype(np.int32)
+
+
+CAT_KEY_SHIFT = 31      # a (node, category) key: node << 31 | id
+
+
+def tree_arrays(tree, m_num: int) -> dict:
     """The flat arrays of a trained tree (the program's `Tree`, or
-    `build_tree`'s dict)."""
+    `build_tree`'s dict), with the left set of each categorical node
+    (feature >= m_num) and their (node, id) keys for routing."""
     get = (tree.get if isinstance(tree, dict)
            else lambda k: getattr(tree, k))
-    return {"feature": np.asarray(get("feature")),
-            "threshold": np.asarray(get("threshold"), np.float32),
-            "children": np.asarray(get("children")),
-            "value": np.asarray(get("value"), np.float64),
-            "n_node": np.asarray(get("n_node"), np.float64),
-            "depth": np.asarray(get("depth"))}
+    t = {"feature": np.asarray(get("feature")),
+         "threshold": np.asarray(get("threshold"), np.float32),
+         "children": np.asarray(get("children")),
+         "value": np.asarray(get("value"), np.float64),
+         "n_node": np.asarray(get("n_node"), np.float64),
+         "depth": np.asarray(get("depth")), "m_num": m_num}
+    nodes = np.flatnonzero(t["feature"] >= m_num)
+    t["cat_left"] = {int(nd): cat_left(tree, nd) for nd in nodes}
+    keys = [(np.int64(nd) << CAT_KEY_SHIFT) + ids[ids >= 0].astype(np.int64)
+            for nd, ids in t["cat_left"].items()]
+    t["cat_keys"] = np.sort(np.concatenate(keys)) if keys else \
+        np.zeros(0, np.int64)
+    return t
 
 
-def walk(t: dict, X: np.ndarray, max_depth: int):
+def go_left(t: dict, data, rows: np.ndarray, nd: np.ndarray) -> np.ndarray:
+    """Whether each row goes left at its split node `nd`: `x <= threshold`
+    at a numeric node, its category in the left set at a categorical
+    one."""
+    f = t["feature"][nd]
+    num = f < t["m_num"]
+    left = np.zeros(len(rows), bool)
+    left[num] = data.X[rows[num], f[num]] <= t["threshold"][nd[num]]
+    if not num.all():
+        r, n_c = rows[~num], nd[~num]
+        ids = data.cat[r, f[~num] - t["m_num"]]
+        left[~num] = np.isin((n_c.astype(np.int64) << CAT_KEY_SHIFT) + ids,
+                             t["cat_keys"])
+    return left
+
+
+def walk(t: dict, data, max_depth: int):
     """Yield (depth, node ids, node of each row) level by level, routing
-    the rows of each split node by the tree's own condition (`x <=
-    threshold` goes left) after each level."""
-    node_of = np.zeros(X.shape[0], np.int64)
+    the rows of each split node by the tree's own condition (`go_left`)
+    after each level."""
+    node_of = np.zeros(data.n, np.int64)
     nodes = np.array([0])
     for depth in range(max_depth + 1):
         yield depth, nodes, node_of
@@ -300,16 +453,15 @@ def walk(t: dict, X: np.ndarray, max_depth: int):
             return
         sel = np.flatnonzero(np.isin(node_of, split_nodes))
         nd = node_of[sel]
-        go_left = X[sel, t["feature"][nd]] <= t["threshold"][nd]
-        node_of[sel] = np.where(go_left, t["children"][nd, 0],
-                                t["children"][nd, 1])
+        node_of[sel] = np.where(go_left(t, data, sel, nd),
+                                t["children"][nd, 0], t["children"][nd, 1])
         nodes = np.sort(t["children"][split_nodes].reshape(-1))
 
 
 def check_tree(tree, data, seed, tree_idx, *, max_depth, min_records=1.0):
     """Walk a trained tree over the data; see the module docstring.
     Returns {"gain_gap", "node_errors", "nodes"}."""
-    t = tree_arrays(tree)
+    t = tree_arrays(tree, data.m_num)
     w = bag_weights(seed, tree_idx, data.n)
     k = m_prime(data.m)
     gap, seen = 0.0, 0
@@ -320,7 +472,7 @@ def check_tree(tree, data, seed, tree_idx, *, max_depth, min_records=1.0):
             rec = errors.setdefault(kind, [0, (depth, int(nodes[bad][0]))])
             rec[0] += int(bad.sum())
 
-    for depth, nodes, node_of in walk(t, data.X, max_depth):
+    for depth, nodes, node_of in walk(t, data, max_depth):
         seen += len(nodes)
         flag("depth", t["depth"][nodes] != depth, depth, nodes)
         pos, totals = _level_state(data, node_of, nodes, w)
@@ -336,9 +488,17 @@ def check_tree(tree, data, seed, tree_idx, *, max_depth, min_records=1.0):
             continue
         cand = candidates(seed, tree_idx, depth, len(nodes), data.m, k)
         cand &= splittable[:, None]
-        best, _, _, held = search_level(
+        held_left = {h: t["cat_left"][int(nd)] for h, nd in enumerate(nodes)
+                     if int(nd) in t["cat_left"]}
+        out_of_range = np.zeros(len(nodes), bool)
+        for h, ids in held_left.items():
+            arity = data.arities[t["feature"][nodes[h]] - data.m_num]
+            out_of_range[h] = ((ids < 0) | (ids >= arity)).any()
+        flag("cat_id_out_of_range", out_of_range, depth, nodes)
+        best, _, _, _, held = search_level(
             data, pos, w, cand, totals, min_records, np.float64,
-            np.where(splits, t["feature"][nodes], -1), t["threshold"][nodes])
+            np.where(splits, t["feature"][nodes], -1), t["threshold"][nodes],
+            held_left)
         best_ok = np.where(np.isfinite(best), best, 0.0)
         bad = splits & ~np.isfinite(held)
         flag("no_candidate_cut", bad, depth, nodes)
@@ -367,6 +527,7 @@ def build_tree(data, seed, tree_idx, *, max_depth, min_records=1.0,
     k = m_prime(data.m)
     feature, threshold, children, value, n_node, depth_of = \
         [], [], [], [], [], []
+    lefts = {}
 
     def new_node(d):
         feature.append(-1)
@@ -393,13 +554,15 @@ def build_tree(data, seed, tree_idx, *, max_depth, min_records=1.0,
             break
         cand = candidates(seed, tree_idx, depth, len(nodes), data.m, k)
         cand &= splittable[:, None]
-        best, feat, thr, _ = search_level(data, pos, w, cand, totals,
-                                          min_records, dtype)
+        best, feat, thr, left_sets, _ = search_level(
+            data, pos, w, cand, totals, min_records, dtype)
         split = splittable & np.isfinite(best) & (best > SPLIT_EPS)
         kids = []
         for h in np.flatnonzero(split):
             nd = nodes[h]
             feature[nd], threshold[nd] = int(feat[h]), thr[h]
+            if feat[h] >= data.m_num:
+                lefts[int(nd)] = left_sets[int(h)]
             children[nd] = [new_node(depth + 1), new_node(depth + 1)]
             kids.extend(children[nd])
         if not kids:
@@ -407,17 +570,20 @@ def build_tree(data, seed, tree_idx, *, max_depth, min_records=1.0,
         split_nodes = nodes[split]
         sel = np.flatnonzero(np.isin(node_of, split_nodes))
         nd = node_of[sel]
-        f = np.asarray(feature)[nd]
-        t = np.asarray(threshold, np.float32)[nd]
-        ch = np.asarray(children)[nd]
-        node_of[sel] = np.where(data.X[sel, f] <= t, ch[:, 0], ch[:, 1])
+        t = tree_arrays({"feature": feature, "threshold": threshold,
+                         "children": children, "value": value,
+                         "n_node": n_node, "depth": depth_of,
+                         "cat_left": lefts}, data.m_num)
+        node_of[sel] = np.where(go_left(t, data, sel, nd),
+                                t["children"][nd, 0], t["children"][nd, 1])
         nodes = np.asarray(kids)
     return {"feature": np.asarray(feature, np.int32),
             "threshold": np.asarray(threshold, np.float32),
             "children": np.asarray(children, np.int32),
             "value": np.stack(value).astype(np.float32),
             "n_node": np.asarray(n_node, np.float32),
-            "depth": np.asarray(depth_of, np.int32)}
+            "depth": np.asarray(depth_of, np.int32),
+            "cat_left": lefts}
 
 
 # ---------------------------------------------------------------------------

@@ -7,7 +7,8 @@ import control
 
 
 @pytest.mark.parametrize("workload", ["higgs.hist", "higgs.exact",
-                                      "covertype.hist.deep"])
+                                      "covertype.hist.deep",
+                                      "higgs.exact.4chip"])
 def test_bfloat16_gains_fail_the_tree_check(workload):
     cell = cell_small.load(workload, rows=4000)
     res = control.train_control(cell, seed=2 ** 31 + 5, trees=2)

@@ -87,3 +87,58 @@ def test_recorded_trace():
     assert max(s["idle_gaps"], key=s["idle_gaps"].get) == "bench.fit"
     b = trace_reduce.breakdown(s)
     assert b["device_ops"][0][0] == "jit_step:fusion"
+
+
+def _reader(name):
+    import harness
+    return harness.load_module(BENCH / "metrics" / f"{name}.py",
+                               f"metric_{name}")
+
+
+def mesh_trace():
+    """Two chips, each running one level program of 1000 ns; chip 0
+    spends 300 ns of it in an all-gather (start 100, done 200), chip 1
+    100 ns in an all-reduce, and both run a fusion and an op of another
+    program."""
+    host = plane("/host:CPU", python=[ev("bench.window", 0, 5000)])
+    level = "jit__fused_level_step_batched({})"
+    devs = []
+    for d, colls in ((0, [ev("all-gather-start.1", 100, 200),
+                          ev("all-gather-done.1", 200, 400)]),
+                     (1, [ev("all-reduce.7", 500, 600)])):
+        devs.append(plane(
+            f"/device:TPU:{d}",
+            XLA_Ops=colls + [ev("fusion.2", 600, 1000),
+                             ev("all-gather.9", 2000, 2500)],
+            XLA_Modules=[ev(level.format(d), 0, 1000),
+                         ev(f"jit_presort({d})", 2000, 2500)]))
+    return [host] + devs
+
+
+def test_collective_share_and_roofline_on_two_chips():
+    s = trace_reduce.reduce_planes(mesh_trace(), device_ids=[0, 1])
+    # per chip: level programs 1000 ns, collectives (300 + 100) / 2 ns;
+    # the presort's all-gather lies outside the level programs
+    assert s["modules"]["jit__fused_level_step_batched"]["seconds"] == \
+        pytest.approx(1000e-9)
+    run = {"trace": s, "device": {"count": 2},
+           "peaks": {"hbm_bytes_per_s": 1e9}, "level_bytes": 1500}
+    assert _reader("level_step.collective_share").read(run) == \
+        pytest.approx(100 * 200 / 1000)
+    # 1500 B at 2 x 1 GB/s is 750 ns of the 1000 ns the programs took
+    assert _reader("level_step_roofline").read(run) == pytest.approx(75.0)
+    one = dict(run, device={"count": 1})
+    assert _reader("level_step_roofline").read(one) == pytest.approx(150.0)
+
+
+def test_collective_share_reads_zero_without_collectives_and_none_without_level_programs():
+    s = trace_reduce.reduce_planes(hand_trace(), device_ids=[0])
+    run = {"trace": s, "device": {"count": 1}}
+    assert _reader("level_step.collective_share").read(run) is None
+    planes = mesh_trace()
+    for p in planes[1:]:
+        p.lines[0].events = [e for e in p.lines[0].events
+                             if e.name.startswith(("fusion", "all-gather.9"))]
+    s = trace_reduce.reduce_planes(planes, device_ids=[0, 1])
+    assert _reader("level_step.collective_share").read(
+        {"trace": s, "device": {"count": 2}}) == 0.0

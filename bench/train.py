@@ -16,8 +16,12 @@ trees is checked in every run (see `check_picks`).
 
 Traffic keys: tree_params (`TreeParams` fields; the library's defaults
 for the rest, the tree batch too), num_trees, rows (the rows the job
-trains on; null = the configuration's), check_trees and limits
-{gain_gap, node_errors}.
+trains on; null = the configuration's), check_trees, limits {gain_gap,
+node_errors} and optionally mesh {data, model}: the fits then run on a
+data x model mesh over the cell's chips with the library's sharded
+engines for the split mode (`core/distributed.py`), at their default
+backends, the categorical one too where the configuration has
+categorical columns.  Without `mesh` a fit is `fit(ds)`.
 """
 from __future__ import annotations
 
@@ -36,13 +40,37 @@ def _params(traffic):
     return TreeParams(**traffic["tree_params"])
 
 
-def _fit(ds, params, traffic, seed):
+def _engines(traffic, params, devices, has_cat) -> dict:
+    """`fit`'s engine arguments: none without a mesh; with one, the
+    library's sharded engines over a data x model mesh of `devices`."""
+    shape = traffic.get("mesh")
+    if shape is None:
+        return {}
+    import jax
+
+    from repro.core import distributed
+    d, k = int(shape["data"]), int(shape["model"])
+    if d * k != len(devices):
+        raise SystemExit(f"mesh {d}x{k} needs {d * k} devices; the cell "
+                         f"has {len(devices)}")
+    mesh = jax.make_mesh((d, k), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2, devices=devices)
+    make = {"exact": distributed.make_2d_sharded_supersplit,
+            "hist": distributed.make_hist_sharded_supersplit}
+    out = {"engine": make[params.split_mode](mesh)}
+    if has_cat:
+        out["cat_engine"] = distributed.make_categorical_sharded_supersplit(
+            mesh)
+    return out
+
+
+def _fit(ds, params, traffic, seed, engines):
     import jax
 
     from repro.core.forest import RandomForest
     rf = RandomForest(params=params, num_trees=traffic["num_trees"],
                       seed=seed)
-    rf.fit(ds)
+    rf.fit(ds, **engines)
     jax.block_until_ready(rf.packed.value)
     return rf.trees
 
@@ -68,14 +96,15 @@ def run(ctx) -> dict:
     from repro.core.dataset import from_numpy
     cfg, traffic, seed = ctx["config"], ctx["traffic"], ctx["seed"]
     n = traffic.get("rows") or cfg["rows"]
-    X, y = ctx["generate"](n, seed)
-    ds = from_numpy(X, None, y)
+    X, cat, y, arities = harness.rows(ctx, n, seed)
+    ds = from_numpy(X, cat, y, arities=arities)
     ds = dataclasses.replace(ds, num=jnp.asarray(ds.num),
                              cat=jnp.asarray(ds.cat),
                              labels=jnp.asarray(ds.labels))
     params = _params(traffic)
     T = traffic["num_trees"]
-    _fit(ds, params, traffic, harness.forest_seed(seed, 0))
+    engines = _engines(traffic, params, ctx["devices"], cat is not None)
+    _fit(ds, params, traffic, harness.forest_seed(seed, 0), engines)
 
     compiles, prof = ctx["compiles"], ctx["profile"]
     c0 = compiles.mark()
@@ -87,7 +116,7 @@ def run(ctx) -> dict:
         fs = harness.forest_seed(seed, len(fits) + 1)
         h0 = host.mark()
         with harness.span("bench.fit"):
-            fits.append((fs, _fit(ds, params, traffic, fs)))
+            fits.append((fs, _fit(ds, params, traffic, fs, engines)))
         per_fit.append(host.since(h0))
         if harness.now() - t0 >= ctx["seconds"]:
             break
@@ -100,7 +129,7 @@ def run(ctx) -> dict:
 
     rate = len(fits) * T * n / (t1 - t0)
     data = reference.Data(X, y, cfg["classes"], params.split_mode,
-                          params.num_bins)
+                          params.num_bins, cat=cat, arities=arities)
     gap, errors = 0.0, 0
     for i, t in check_picks(seed, len(fits), T, traffic["check_trees"]):
         fs, trees = fits[i]
@@ -123,12 +152,11 @@ def run(ctx) -> dict:
                              for k in per_fit[0]}},
     }
     if prof.summary is not None:
-        levels = [roofline.tree_levels(tr, X, fs, t, params.max_depth,
+        levels = [roofline.tree_levels(tr, data, fs, t, params.max_depth,
                                        params.min_records)
                   for fs, trees in fits for t, tr in enumerate(trees)]
         out["layer_inputs"] = {
             "level_bytes": sum(roofline.level_bytes(
-                lv, m=X.shape[1], mode=params.split_mode,
-                num_bins=params.num_bins, num_classes=cfg["classes"])
-                for lv in levels)}
+                lv, mode=params.split_mode, num_bins=params.num_bins,
+                num_classes=cfg["classes"]) for lv in levels)}
     return out
